@@ -66,6 +66,10 @@ def data_parallel_step(
     - gradient reduction: implicit — the weighted-mean loss over the
       sharded batch makes XLA emit the cross-replica psum (rabit's
       allreduce, moved into the compiler)
+
+    The returned step's ``jitted(params, batch)`` gives the jitted
+    function itself, for ahead-of-time ``.lower(...).compile()`` on
+    ShapeDtypeStructs (tests/test_tpu_compile.py).
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
@@ -98,7 +102,9 @@ def data_parallel_step(
         )
         return (p, b, nargs)
 
-    def run(params, batch, *args):
+    def jitted(params, batch, *args):
+        """The jitted step for these arguments' structure (arrays or
+        ShapeDtypeStructs — what an ahead-of-time ``.lower`` needs)."""
         # one jit per (pytree structure, ndims) so switching batch layouts
         # (ell ↔ dense) re-derives the shardings
         key = structure_key(params, batch, len(args))
@@ -112,6 +118,10 @@ def data_parallel_step(
                 donate_argnums=(0,) if donate_params else (),
             )
             compiled[key] = fn
-        return fn(params, batch, *args)
+        return fn
 
+    def run(params, batch, *args):
+        return jitted(params, batch, *args)(params, batch, *args)
+
+    run.jitted = jitted
     return run

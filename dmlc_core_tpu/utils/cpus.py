@@ -6,7 +6,7 @@ or a restricted affinity mask can leave a process with a fraction of the
 host's cores, and a pool sized to the host then just adds GIL churn and
 scheduler thrash. Conversely, a bench container pinned to one core of a
 many-core host must not pretend the host has one CPU when the affinity
-mask says otherwise (BENCH_r05 reported ``host_cpus: 1``).
+mask says otherwise (the pre-PR-1 chip runs reported ``host_cpus: 1``).
 
 ``available_cpus()`` returns the effective parallelism:
 

@@ -45,18 +45,20 @@ N_FEATURES = 1 << 14
 K = 39  # 13 dense + 26 categorical, Criteo-shaped
 
 
-def synth(path: str, rows: int = 20000) -> None:
+def synth(
+    path: str, rows: int = 20000, n_features: int = N_FEATURES, seed: int = 0
+) -> None:
     from dmlc_core_tpu.data.row_block import RowBlock
     from dmlc_core_tpu.data.rowrec import write_rowrec
     from dmlc_core_tpu.io.stream import FileStream
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     idx = np.empty((rows, K), dtype=np.uint32)
     idx[:, :13] = np.arange(13)
-    idx[:, 13:] = rng.integers(13, N_FEATURES, (rows, 26))
+    idx[:, 13:] = rng.integers(13, n_features, (rows, 26))
     val = np.ones((rows, K), dtype=np.float32)
     val[:, :13] = rng.uniform(0, 1, (rows, 13))
-    w = rng.normal(size=N_FEATURES) / np.sqrt(K)
+    w = rng.normal(size=n_features) / np.sqrt(K)
     logits = (w[idx] * val).sum(axis=1)
     labels = (logits > 0).astype(np.float32)
     blk = RowBlock(
@@ -386,4 +388,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from dmlc_core_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

@@ -79,4 +79,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from dmlc_core_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     main()

@@ -171,7 +171,8 @@ def test_record_mode_resumes_at_any_position():
 def test_gather_beats_legacy_per_record_loop():
     """Bench invariant (tier-1-safe): on a small synthetic shard the
     gather path must beat the legacy per-record seek loop — the 13x
-    shuffled-read wall (BENCH_r05) cannot silently come back. Generous
+    shuffled-read wall of the pre-PR-1 chip runs cannot silently come
+    back. Generous
     margin: the gap is >10x on every host measured; 1.5x catches a
     dead fast path without flaking on a loaded CI box."""
     records = [bytes([i % 251]) * 120 for i in range(20000)]

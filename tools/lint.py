@@ -201,6 +201,7 @@ DEFAULT_PATHS = [
     "tools",
     "examples",
     "bench.py",
+    "chip_smoke.py",
     "__graft_entry__.py",
 ]
 
