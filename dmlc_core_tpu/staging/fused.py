@@ -54,6 +54,7 @@ from ..io.recordio import CFLAG_COMPRESSED, KMAGIC, decode_flag
 from ..io.uri import URISpec, rejoin_query, uri_int
 from ..telemetry import default_registry as _default_registry
 from ..utils.logging import Error, check
+from ..utils.profiler import annotate
 from .batcher import Batch, BatchSpec, alloc_packed_slot, gather_slices
 
 # registry mirrors of the per-producer counters (the per-instance
@@ -270,9 +271,10 @@ class _FusedTextBatches:
         # an open mmap/fd
         self._split_args = (part_index, num_parts)
         self._split = None
-        self._ring: List[Tuple[np.ndarray, ...]] = [
-            self._alloc_slot() for _ in range(max(2, ring))
-        ]
+        with annotate("dmlc:producer_open"):
+            self._ring: List[Tuple[np.ndarray, ...]] = [
+                self._alloc_slot() for _ in range(max(2, ring))
+            ]
         self.ring_slots = len(self._ring)
         self._slot = 0
         self.rows_in = 0
@@ -309,13 +311,14 @@ class _FusedTextBatches:
             local = (
                 _plain_local_path(self.uspec.uri) if num_parts == 1 else None
             )
-            self._split = (
-                _MmapChunks(local)
-                if local is not None
-                else io_split.create(
-                    self.uspec.uri, part_index, num_parts, type="text"
+            with annotate("dmlc:producer_open"):
+                self._split = (
+                    _MmapChunks(local)
+                    if local is not None
+                    else io_split.create(
+                        self.uspec.uri, part_index, num_parts, type="text"
+                    )
                 )
-            )
         return self._split
 
     def __iter__(self) -> Iterator[Batch]:
@@ -325,7 +328,8 @@ class _FusedTextBatches:
         fill = 0
         first = True
         while True:
-            chunk = split.next_chunk()
+            with annotate("dmlc:fetch"):
+                chunk = split.next_chunk()
             if chunk is None:
                 break
             off = 0
@@ -335,9 +339,10 @@ class _FusedTextBatches:
             n = len(chunk)
             cr_hint = -1  # probe once per chunk, cache across resumed calls
             while off < n:
-                rows, consumed, cr_hint = self._parse(
-                    chunk, off, slot, fill, cr_hint
-                )
+                with annotate("dmlc:parse"):
+                    rows, consumed, cr_hint = self._parse(
+                        chunk, off, slot, fill, cr_hint
+                    )
                 if consumed == 0 and rows == 0:
                     break  # defensive: no forward progress
                 off += consumed
@@ -611,17 +616,18 @@ class FusedEllRowRecBatches(_EllSlotMixin):
         # forward path + query (fragment stripped, matching the mmap fast
         # path): io_split.create resolves the sugar (shuffle_parts /
         # index / seed) itself
-        self._split = (
-            _MmapRawChunks(local)
-            if local is not None
-            else io_split.create(
-                uspec.uri + rejoin_query(uspec.args),
-                part_index, num_parts, type="recordio",
+        with annotate("dmlc:producer_open"):
+            self._split = (
+                _MmapRawChunks(local)
+                if local is not None
+                else io_split.create(
+                    uspec.uri + rejoin_query(uspec.args),
+                    part_index, num_parts, type="recordio",
+                )
             )
-        )
-        self._ring: List[Tuple[np.ndarray, ...]] = [
-            self._alloc_ell_slot() for _ in range(max(2, ring))
-        ]
+            self._ring: List[Tuple[np.ndarray, ...]] = [
+                self._alloc_ell_slot() for _ in range(max(2, ring))
+            ]
         self.ring_slots = len(self._ring)
         self._slot = 0
         self.rows_in = 0
@@ -652,9 +658,10 @@ class FusedEllRowRecBatches(_EllSlotMixin):
         """Parse chunk[off:] into the current slot; returns updated
         (off, fill, made_progress)."""
         indices, values, nnz, labels, weights, _packed = self._ring[self._slot]
-        rows, consumed, trunc, bad, corrupt = native.parse_rowrec_ell(
-            chunk, off, indices, values, nnz, labels, weights, fill
-        )
+        with annotate("dmlc:parse"):
+            rows, consumed, trunc, bad, corrupt = native.parse_rowrec_ell(
+                chunk, off, indices, values, nnz, labels, weights, fill
+            )
         self.rows_in += rows
         self.truncated_nnz += trunc
         self.bad_records += bad
@@ -683,7 +690,8 @@ class FusedEllRowRecBatches(_EllSlotMixin):
             return
         carry = b""
         while True:
-            chunk = self._split.next_chunk()
+            with annotate("dmlc:fetch"):
+                chunk = self._split.next_chunk()
             if chunk is None:
                 break
             if carry:
@@ -725,7 +733,8 @@ class FusedEllRowRecBatches(_EllSlotMixin):
         fill = 0
         use_native = native.HAS_GATHER_ELL
         while True:
-            g = self._split.next_gather_batch(B - fill)
+            with annotate("dmlc:fetch"):
+                g = self._split.next_gather_batch(B - fill)
             if g is None:
                 break
             buf, starts, sizes = g
@@ -747,12 +756,13 @@ class FusedEllRowRecBatches(_EllSlotMixin):
             while off < n:
                 slot = self._ring[self._slot]
                 indices, values, nnz, labels, weights, _packed = slot
-                rows, consumed, trunc, bad, corrupt = (
-                    native.parse_rowrec_gather_ell(
-                        buf, starts, sizes, off, n - off,
-                        indices, values, nnz, labels, weights, fill,
+                with annotate("dmlc:parse"):
+                    rows, consumed, trunc, bad, corrupt = (
+                        native.parse_rowrec_gather_ell(
+                            buf, starts, sizes, off, n - off,
+                            indices, values, nnz, labels, weights, fill,
+                        )
                     )
-                )
                 self.rows_in += rows
                 self.truncated_nnz += trunc
                 self.bad_records += bad
@@ -780,7 +790,8 @@ class FusedEllRowRecBatches(_EllSlotMixin):
         B = self.spec.batch_size
         fill = 0
         while True:
-            chunk = self._split.window()
+            with annotate("dmlc:fetch"):
+                chunk = self._split.window()
             if chunk is None:
                 break
             off, n = 0, len(chunk)

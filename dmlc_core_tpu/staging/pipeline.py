@@ -68,7 +68,7 @@ logger = logging.getLogger("dmlc_core_tpu.staging")
 _PAGE = 4096  # dispatch-ring slot buffers are page-aligned (DMA-friendly)
 
 # telemetry series (docs/observability.md). The per-pipeline
-# ``stage_seconds`` sums stay for r1-r5 bench comparability; the
+# ``stage_seconds`` sums feed the chip benchmark's readers; the
 # registry carries the same stage timings as log-bucketed duration
 # HISTOGRAMS (one series per stage label), so the tail — a stalled
 # host_pull, one 2-second dispatch — is visible, not averaged away.
@@ -509,11 +509,15 @@ def _pack_shards(
 def _put_packed(src, layout, device, stats: Optional[StagingStats]):
     """One u8 DMA + on-device bitcast unpack (single-device path)."""
     jax = _require_jax()
-    u8 = jax.device_put(src, device)
-    if stats is not None:
-        stats.tick_puts([device])
-        stats.tick_batch("packed")
-    return _unpacker(layout, device.platform)(u8)
+    with annotate("dmlc:ring_dispatch"):
+        with annotate("dmlc:dma_put"):
+            u8 = jax.device_put(src, device)
+        if stats is not None:
+            stats.tick_puts([device])
+            stats.tick_batch("packed")
+        unpack = _unpacker(layout, device.platform)
+        with annotate("dmlc:unpack_call"):
+            return unpack(u8)
 
 
 def _put_packed_shards(
@@ -528,23 +532,29 @@ def _put_packed_shards(
     jax = _require_jax()
     from jax.sharding import NamedSharding, PartitionSpec
 
-    n_shards = mesh.shape[data_axis]
-    platform = mesh.devices.flat[0].platform
-    sharding = NamedSharding(mesh, PartitionSpec(data_axis))
-    gshape = (n_shards * stride,)
-    idx_map = sharding.addressable_devices_indices_map(gshape)
-    devs = list(idx_map)
-    arrs = []
-    for dev, idx in idx_map.items():
-        start = idx[0].start or 0
-        arrs.append(jax.device_put(src[int(start) // stride], dev))
-    garr = jax.make_array_from_single_device_arrays(gshape, sharding, arrs)
-    if stats is not None:
-        stats.tick_puts(devs)
-        stats.tick_batch("packed_shard")
-    return _shard_unpacker(shard_entries, stride, mesh, data_axis, platform)(
-        garr
-    )
+    with annotate("dmlc:ring_dispatch"):
+        n_shards = mesh.shape[data_axis]
+        platform = mesh.devices.flat[0].platform
+        sharding = NamedSharding(mesh, PartitionSpec(data_axis))
+        gshape = (n_shards * stride,)
+        idx_map = sharding.addressable_devices_indices_map(gshape)
+        devs = list(idx_map)
+        with annotate("dmlc:dma_put"):
+            arrs = []
+            for dev, idx in idx_map.items():
+                start = idx[0].start or 0
+                arrs.append(jax.device_put(src[int(start) // stride], dev))
+            garr = jax.make_array_from_single_device_arrays(
+                gshape, sharding, arrs
+            )
+        if stats is not None:
+            stats.tick_puts(devs)
+            stats.tick_batch("packed_shard")
+        unpack = _shard_unpacker(
+            shard_entries, stride, mesh, data_axis, platform
+        )
+        with annotate("dmlc:unpack_call"):
+            return unpack(garr)
 
 
 def _stage_per_array_mesh(
@@ -722,30 +732,16 @@ class StagingPipeline:
         # per-stage wall-clock accumulators (seconds). host_pull /
         # dispatch_* tick on the transfer thread, transfer_wait on the
         # consumer thread, and the ring workers' blocking dispatches
-        # overlap all of them — the sum may exceed wall-clock.
-        # stage_dispatch = dispatch_pack + dispatch_put is kept as an
-        # explicit key for r1-r5 comparability (bench aggregates these).
+        # overlap all of them — the sum may exceed wall-clock. Each
+        # stage also feeds a registry duration histogram.
         self.stage_seconds: Dict[str, float] = {
             "host_pull": 0.0,
             "dispatch_pack": 0.0,
             "dispatch_put": 0.0,
             "dispatch_slot_wait": 0.0,
-            "stage_dispatch": 0.0,
             "transfer_wait": 0.0,
         }
-        # registry duration histograms, one per REAL stage (the derived
-        # stage_dispatch sum is not re-observed — it would double-count
-        # pack+put samples); ISSUE 4: timing splits become histograms
-        self._stage_hists = {
-            k: _stage_hist(k)
-            for k in (
-                "host_pull",
-                "dispatch_pack",
-                "dispatch_put",
-                "dispatch_slot_wait",
-                "transfer_wait",
-            )
-        }
+        self._stage_hists = {k: _stage_hist(k) for k in self.stage_seconds}
         self.staging = StagingStats()
         # _shard_plan is a once-per-config property (the batcher emits
         # fixed shapes); memoized by shape/dtype signature so the hot
@@ -757,27 +753,29 @@ class StagingPipeline:
         # slot), depth+2 slots — the transfer thread packs into one
         # while `depth` futures sit in the device queue and one batch is
         # with the consumer
-        self._exec = ThreadPoolExecutor(
-            max_workers=self._depth, thread_name_prefix="staging-put"
-        )
-        self._slots = [_SlotBuf() for _ in range(self._depth + 2)]
-        self._slot_i = 0
-        self._host_iter: ThreadedIter[Batch] = ThreadedIter(
-            lambda: iter(host_batches), max_capacity=prefetch, name="staging"
-        )
-        # device_put may BLOCK during dispatch (a backend is free to
-        # complete the "async" transfer before device_put returns).
-        # Staging inline on the consumer thread would then serialize
-        # transfers with the consumer's compute and the in-flight `depth`
-        # would overlap nothing. The transfer thread + ring workers keep
-        # the overlap whatever the platform's dispatch semantics: parse
-        # threads, packing, device_put, and consumer compute each run on
-        # their own thread, meeting at bounded queues (the reference's
-        # pipeline discipline, threaded_input_split.h:33, one level
-        # further down).
-        self._xfer_iter: ThreadedIter[Any] = ThreadedIter(
-            self._staged, max_capacity=self._depth, name="staging-xfer"
-        )
+        with annotate("dmlc:pipeline_open"):
+            self._exec = ThreadPoolExecutor(
+                max_workers=self._depth, thread_name_prefix="staging-put"
+            )
+            self._slots = [_SlotBuf() for _ in range(self._depth + 2)]
+            self._slot_i = 0
+            self._host_iter: ThreadedIter[Batch] = ThreadedIter(
+                lambda: iter(host_batches), max_capacity=prefetch,
+                name="staging",
+            )
+            # device_put may BLOCK during dispatch (a backend is free to
+            # complete the "async" transfer before device_put returns).
+            # Staging inline on the consumer thread would then serialize
+            # transfers with the consumer's compute and the in-flight `depth`
+            # would overlap nothing. The transfer thread + ring workers keep
+            # the overlap whatever the platform's dispatch semantics: parse
+            # threads, packing, device_put, and consumer compute each run on
+            # their own thread, meeting at bounded queues (the reference's
+            # pipeline discipline, threaded_input_split.h:33, one level
+            # further down).
+            self._xfer_iter: ThreadedIter[Any] = ThreadedIter(
+                self._staged, max_capacity=self._depth, name="staging-xfer"
+            )
 
     def _platform(self) -> str:
         if self._mesh is not None:
@@ -803,14 +801,10 @@ class StagingPipeline:
             return None
         return plan
 
-    def _observe(self, key: str, dt: float, dispatch: bool = False) -> None:
-        """One stage timing sample: tick the legacy per-pipeline sum
-        (bench r1-r5 comparability) and the registry duration histogram
-        (``staging.stage_seconds{stage=...}``). ``dispatch`` also feeds
-        the derived ``stage_dispatch`` sum (= pack + put)."""
+    def _observe(self, key: str, dt: float) -> None:
+        """One stage timing sample: tick the per-pipeline sum and the
+        registry duration histogram (``staging.stage_seconds{stage=...}``)."""
         self.stage_seconds[key] += dt
-        if dispatch:
-            self.stage_seconds["stage_dispatch"] += dt
         self._stage_hists[key].observe(dt)
 
     def _next_slot(self) -> _SlotBuf:
@@ -857,7 +851,7 @@ class StagingPipeline:
                         host, shard_entries, stride, n_shards, platform,
                         slot,
                     )
-                self._observe("dispatch_pack", get_time() - t0, dispatch=True)
+                self._observe("dispatch_pack", get_time() - t0)
                 t0 = get_time()
                 with annotate("dmlc:dispatch_put"):
                     item = self._exec.submit(
@@ -866,7 +860,7 @@ class StagingPipeline:
                     )
                 if platform != "cpu":
                     slot.pending = item
-                self._observe("dispatch_put", get_time() - t0, dispatch=True)
+                self._observe("dispatch_put", get_time() - t0)
             elif layout is not None:
                 if self._adopt and adoptable_slot(host):
                     # zero-copy adopt: device_put straight from the
@@ -884,9 +878,7 @@ class StagingPipeline:
                         )
                     self.slots_adopted += 1
                     _SLOTS_ADOPTED.inc()
-                    self._observe(
-                        "dispatch_put", get_time() - t0, dispatch=True
-                    )
+                    self._observe("dispatch_put", get_time() - t0)
                 else:
                     if self._adopt:
                         # adopt-capable producer but this buffer failed
@@ -896,9 +888,7 @@ class StagingPipeline:
                     t0 = get_time()
                     with annotate("dmlc:dispatch_pack"):
                         src = _pack_single(host, platform, slot)
-                    self._observe(
-                        "dispatch_pack", get_time() - t0, dispatch=True
-                    )
+                    self._observe("dispatch_pack", get_time() - t0)
                     t0 = get_time()
                     with annotate("dmlc:dispatch_put"):
                         item = self._exec.submit(
@@ -907,9 +897,7 @@ class StagingPipeline:
                         )
                     if platform != "cpu":
                         slot.pending = item
-                    self._observe(
-                        "dispatch_put", get_time() - t0, dispatch=True
-                    )
+                    self._observe("dispatch_put", get_time() - t0)
             else:
                 # per-array fallback: host buffers stay referenced until
                 # the DMA completes, so dispatch stays on this thread and
@@ -933,7 +921,7 @@ class StagingPipeline:
                         self.staging.tick_raw_puts(len(dev))
                         self.staging.tick_batch("per_array")
                     item = _Ready(dev)
-                self._observe("dispatch_put", get_time() - t0, dispatch=True)
+                self._observe("dispatch_put", get_time() - t0)
             self.rows_staged += host.n_valid
             self.batches_staged += 1
             nbytes = sum(v.nbytes for v in host.as_dict().values())
@@ -954,7 +942,8 @@ class StagingPipeline:
         # fires)
         try:
             while True:
-                item = self._xfer_iter.next()
+                with annotate("dmlc:xfer_queue_wait"):
+                    item = self._xfer_iter.next()
                 if item is None:
                     return
                 # Force this batch's transfer to complete before handing
@@ -1000,12 +989,13 @@ class StagingPipeline:
         # join timed out: the orphaned thread may still touch the host
         # batch source, so the caller must not tear down mmap-backed
         # producers until it has actually exited.
-        host_joined = self._host_iter.destroy(timeout=1.0)
-        xfer_joined = self._xfer_iter.destroy(timeout=1.0)
-        # ring workers read only pipeline-owned slot buffers (never the
-        # producer's ring), so an unfinished dispatch can drain after the
-        # sources are gone; no join needed beyond letting them finish
-        self._exec.shutdown(wait=False, cancel_futures=True)
+        with annotate("dmlc:pipeline_close"):
+            host_joined = self._host_iter.destroy(timeout=1.0)
+            xfer_joined = self._xfer_iter.destroy(timeout=1.0)
+            # ring workers read only pipeline-owned slot buffers (never
+            # the producer's ring), so an unfinished dispatch can drain
+            # after the sources are gone; no join beyond letting them end
+            self._exec.shutdown(wait=False, cancel_futures=True)
         if not (host_joined and xfer_joined):
             self.close_timed_out = True
         return host_joined and xfer_joined
@@ -1052,8 +1042,9 @@ def drain_close(pipe: StagingPipeline, *sources) -> bool:
             len(sources),
         )
         return False
-    for s in sources:
-        close = getattr(s, "close", None)
-        if close is not None:
-            close()
+    with annotate("dmlc:pipeline_close"):
+        for s in sources:
+            close = getattr(s, "close", None)
+            if close is not None:
+                close()
     return True

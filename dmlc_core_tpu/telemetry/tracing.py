@@ -827,6 +827,7 @@ _WAIT_STAGES = frozenset(
         "host_pull",          # transfer thread starved by the parse ring
         "dispatch_slot_wait",  # slot reuse gated on an unfinished DMA
         "transfer_wait",      # consumer blocked on an incomplete transfer
+        "xfer_queue_wait",    # consumer blocked on the staged-batch queue
         "retry_backoff",      # remote IO healing a transient failure
         "gather_refill",      # split consumer starved by the window loader
         "fetch_wait",         # window loader starved by remote span reads

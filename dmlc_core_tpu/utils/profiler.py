@@ -69,21 +69,29 @@ def _jax_profiler(force: bool = False):
 # -- span duration histograms (opt-in) ----------------------------------------
 
 _HIST_OVERRIDE: Optional[bool] = None  # enable_histograms() wins over env
+_HIST_ENV: Optional[bool] = None  # resolved once; enable_histograms() clears
 _SPAN_HISTS: Dict[str, object] = {}  # name -> Histogram (memoized lookup)
 
 
 def histograms_enabled() -> bool:
-    """Are annotate() spans feeding duration histograms?"""
+    """Are annotate() spans feeding duration histograms? The
+    ``DMLC_PROFILE_HIST`` env is read once (every span on every thread
+    asks), as ``telemetry.tracing.enabled()`` reads ``DMLC_TRACE``."""
     if _HIST_OVERRIDE is not None:
         return _HIST_OVERRIDE
-    return os.environ.get("DMLC_PROFILE_HIST", "0") not in ("", "0", "false")
+    global _HIST_ENV
+    if _HIST_ENV is None:
+        raw = os.environ.get("DMLC_PROFILE_HIST", "0")
+        _HIST_ENV = raw not in ("", "0", "false")
+    return _HIST_ENV
 
 
 def enable_histograms(on: Optional[bool]) -> None:
     """Force span histograms on/off for this process (None restores the
-    ``DMLC_PROFILE_HIST`` env default)."""
-    global _HIST_OVERRIDE
+    ``DMLC_PROFILE_HIST`` env default, re-read at the next span)."""
+    global _HIST_OVERRIDE, _HIST_ENV
     _HIST_OVERRIDE = on
+    _HIST_ENV = None
 
 
 _SPAN_MEMO_CAP = 256  # span names are static call sites, not data

@@ -74,12 +74,16 @@ def test_annotate_feeds_duration_histograms_when_enabled(no_jax, hist_off):
 
 
 def test_histograms_env_default(monkeypatch, hist_off):
-    profiler.enable_histograms(None)
     monkeypatch.delenv("DMLC_PROFILE_HIST", raising=False)
+    profiler.enable_histograms(None)
     assert profiler.histograms_enabled() is False
     monkeypatch.setenv("DMLC_PROFILE_HIST", "1")
+    # the env is resolved once: a change shows only after a reset
+    assert profiler.histograms_enabled() is False
+    profiler.enable_histograms(None)
     assert profiler.histograms_enabled() is True
     monkeypatch.setenv("DMLC_PROFILE_HIST", "0")
+    profiler.enable_histograms(None)
     assert profiler.histograms_enabled() is False
     # explicit override beats the env
     profiler.enable_histograms(True)

@@ -176,17 +176,14 @@ def test_staging_pipeline_end_to_end():
     # and reported both on the attribute and through throughput()
     assert set(pipe.stage_seconds) == {
         "host_pull", "dispatch_pack", "dispatch_put",
-        "dispatch_slot_wait", "stage_dispatch", "transfer_wait",
+        "dispatch_slot_wait", "transfer_wait",
     }
     assert all(v >= 0 for v in pipe.stage_seconds.values())
-    assert pipe.stage_seconds["stage_dispatch"] > 0
-    assert pipe.stage_seconds["stage_dispatch"] == pytest.approx(
-        pipe.stage_seconds["dispatch_pack"]
-        + pipe.stage_seconds["dispatch_put"]
-    )
-    assert stats["secs_stage_dispatch"] == (
-        pipe.stage_seconds["stage_dispatch"]
-    )
+    assert pipe.stage_seconds["dispatch_pack"] > 0
+    assert pipe.stage_seconds["dispatch_put"] > 0
+    for k, v in pipe.stage_seconds.items():
+        assert stats[f"secs_{k}"] == v
+    assert "secs_stage_dispatch" not in stats
     # packed single-DMA path engaged (the generic batcher packs too now)
     st = pipe.staging_stats()
     assert st["packed_batches"] == 4 and st["per_array_batches"] == 0

@@ -222,9 +222,8 @@ def test_pipeline_mesh_packed_shard_stats(tmp_path):
     assert st["dispatch_ring_depth"] >= 1
     assert pipe.io_stats()["staging"]["packed_shard_dma"] is True
     secs = pipe.stage_seconds
-    assert secs["stage_dispatch"] == pytest.approx(
-        secs["dispatch_pack"] + secs["dispatch_put"]
-    )
+    assert "stage_dispatch" not in secs
+    assert secs["dispatch_pack"] > 0 and secs["dispatch_put"] > 0
     drain_close(pipe, stream)
 
 
