@@ -5,6 +5,10 @@ Second-order FM (Rendle 2010): score = w0 + Σ w_i x_i
 gathers — the classic trick that keeps it O(B·K·E) with no D×D term. The
 embedding table is the natural tensor-parallel shard target: split the E
 axis over the mesh's 'model' axis (see parallel/ and __graft_entry__).
+
+``sgd_step`` updates only the rows a batch touches when the loss has no
+l2 term (see its docstring); with l2 every row has a gradient and the
+step stays dense.
 """
 
 from __future__ import annotations
@@ -14,13 +18,64 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.sparse import ell_matvec, weighted_mean
+from ..ops.sparse import weighted_mean
+from ..telemetry.registry import default_registry
 from .common import bce_with_logits, sgd_update
 
 __all__ = ["FactorizationMachine"]
 
 Params = Dict[str, jax.Array]
 Batch = Dict[str, jax.Array]
+
+
+def _count_update_path(path: str) -> None:
+    """One tick per trace of ``sgd_step``: which update it compiled to."""
+    default_registry().counter(
+        "models.fm.update_path",
+        help="FM sgd_step traces by update path "
+        "(sparse | sparse-replicated | dense)",
+        labels={"path": path},
+    ).inc()
+
+
+def _done_on_every_device(params: Params, loss: jax.Array, mesh):
+    """Return ``loss`` only once every device of ``mesh`` holds ``params``.
+
+    After the all-gather no collective follows the scatter, so the
+    devices finish the step at different times, while a caller waiting
+    on the replicated loss reads it from one device. A caller that runs
+    ahead (two steps in flight, no donation) then allocates the next
+    step's tables while a slower device still holds this step's input:
+    one table copy more on that device (12.1 against 9.7 GB a device on
+    the 2x2 v5e mesh at 2^26 x 16). The barrier orders the loss after
+    the updated tables, and the max over the whole mesh (exact: every
+    device holds the same loss) makes it wait for the slowest device.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    params, loss = jax.lax.optimization_barrier((params, loss))
+    loss = jax.shard_map(
+        lambda x: jax.lax.pmax(x, mesh.axis_names),
+        mesh=mesh, in_specs=P(), out_specs=P(),
+    )(loss)
+    return params, loss
+
+
+def _scores(w_rows, v_rows, b, values, k_axis: int = 1) -> jax.Array:
+    """FM scores from the gathered rows: w_rows and values [B, K], v_rows
+    [B, K, E] (``k_axis=0``: [K, B] and [K, B, E])."""
+    linear = jnp.sum(values * w_rows, axis=k_axis)
+    xv = v_rows * values[..., None]
+    sum_sq = jnp.sum(xv, axis=k_axis) ** 2  # [B, E]
+    sq_sum = jnp.sum(xv**2, axis=k_axis)  # [B, E]
+    pair = 0.5 * jnp.sum(sum_sq - sq_sum, axis=-1)  # [B]
+    return linear + pair + b
+
+
+def _rows_loss(rows: Params, batch: Batch, k_axis: int = 1) -> jax.Array:
+    scores = _scores(rows["w"], rows["v"], rows["b"], batch["values"], k_axis)
+    per_row = bce_with_logits(scores, batch["labels"])
+    return weighted_mean(per_row, batch["weights"])
 
 
 class FactorizationMachine:
@@ -43,20 +98,21 @@ class FactorizationMachine:
             "b": jnp.zeros((), jnp.float32),
         }
 
+    @staticmethod
+    def _rows(params: Params, idx: jax.Array) -> Params:
+        """The rows ``idx`` gathers: w [*idx], v [*idx, E], and the bias."""
+        return {
+            "w": jnp.take(params["w"], idx, axis=0),
+            "v": jnp.take(params["v"], idx, axis=0),
+            "b": params["b"],
+        }
+
     def forward(self, params: Params, batch: Batch) -> jax.Array:
-        idx, val = batch["indices"], batch["values"]
-        linear = ell_matvec(idx, val, params["w"])
-        emb = jnp.take(params["v"], idx, axis=0)  # [B, K, E]
-        xv = emb * val[..., None]  # [B, K, E]
-        sum_sq = jnp.sum(xv, axis=1) ** 2  # [B, E]
-        sq_sum = jnp.sum(xv**2, axis=1)  # [B, E]
-        pair = 0.5 * jnp.sum(sum_sq - sq_sum, axis=-1)  # [B]
-        return linear + pair + params["b"]
+        rows = self._rows(params, batch["indices"])
+        return _scores(rows["w"], rows["v"], rows["b"], batch["values"])
 
     def loss(self, params: Params, batch: Batch) -> jax.Array:
-        scores = self.forward(params, batch)
-        per_row = bce_with_logits(scores, batch["labels"])
-        data_loss = weighted_mean(per_row, batch["weights"])
+        data_loss = _rows_loss(self._rows(params, batch["indices"]), batch)
         if self.l2:
             data_loss = data_loss + self.l2 * (
                 jnp.sum(params["w"] ** 2) + jnp.sum(params["v"] ** 2)
@@ -68,11 +124,58 @@ class FactorizationMachine:
     ) -> Tuple[jax.Array, Params]:
         """(loss, grads) without the update — see
         ``linear._LinearBase.loss_and_grads``: the half step a
-        multi-host SGD loop allreduces before one shared update."""
+        multi-host SGD loop allreduces before one shared update. The
+        gradients are dense (table-shaped) whatever ``l2`` is."""
         return jax.value_and_grad(self.loss)(params, batch)
 
     def sgd_step(
         self, params: Params, batch: Batch, lr: float = 0.05
     ) -> Tuple[Params, jax.Array]:
-        loss_val, grads = self.loss_and_grads(params, batch)
-        return sgd_update(params, grads, lr), loss_val
+        """One SGD step: p − lr·∇p.
+
+        With ``l2 == 0`` only the rows the batch gathers have a gradient,
+        so the step differentiates with respect to the gathered rows and
+        scatter-adds −lr·(row gradient) into ``w`` and ``v`` at the
+        batch's ids (duplicate ids sum, as the dense gradient sums them):
+        no table-sized gradient is built, zeroed or subtracted. Traced
+        under a mesh (``parallel.data_parallel_step`` sets it), the ids
+        and row gradients are first replicated over the mesh, so the
+        partitioner all-gathers the rows instead of all-reducing a
+        table-sized gradient over the data axis, and the loss is
+        returned through a max over the mesh taken after the update (see
+        ``_done_on_every_device``). With ``l2 > 0`` every row has a
+        gradient and the step is the dense one.
+        """
+        if self.l2:
+            _count_update_path("dense")
+            loss_val, grads = self.loss_and_grads(params, batch)
+            return sgd_update(params, grads, lr), loss_val
+        # K-major rows ([K, B, E]): the row gradient's minor dimension is
+        # then the batch, which the TPU tiles unpadded; B-major puts K
+        # minor and pads it to 128 lanes (at the Criteo shape, K = 39, a
+        # 67 MB [B, K, E] temp in HBM instead of 21 MB)
+        idx = batch["indices"].T
+        loss_val, g = jax.value_and_grad(_rows_loss)(
+            self._rows(params, idx), dict(batch, values=batch["values"].T), 0
+        )
+        ids = idx.reshape(-1)
+        gw = g["w"].reshape(-1)
+        gv = g["v"].reshape(-1, self.embed_dim)
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            _count_update_path("sparse")
+        else:
+            _count_update_path("sparse-replicated")
+            ids, gw, gv = jax.lax.with_sharding_constraint(
+                (ids, gw, gv), jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec()
+                ),
+            )
+        new = {
+            "w": params["w"].at[ids].add(-lr * gw),
+            "v": params["v"].at[ids].add(-lr * gv),
+            "b": params["b"] - lr * g["b"],
+        }
+        if not mesh.empty:
+            new, loss_val = _done_on_every_device(new, loss_val, mesh)
+        return new, loss_val

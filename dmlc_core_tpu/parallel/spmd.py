@@ -66,11 +66,17 @@ def data_parallel_step(
     - gradient reduction: implicit — the weighted-mean loss over the
       sharded batch makes XLA emit the cross-replica psum (rabit's
       allreduce, moved into the compiler)
+    - ``step_fn`` is traced with ``mesh`` as the context mesh
+      (``jax.sharding.get_abstract_mesh()`` reads it), so a model can
+      constrain its intermediates on the mesh without a mesh argument
+      (``FactorizationMachine.sgd_step`` replicates its row gradients)
 
     The returned step's ``jitted(params, batch)`` gives the jitted
     function itself, for ahead-of-time ``.lower(...).compile()`` on
     ShapeDtypeStructs (tests/test_tpu_compile.py).
     """
+    import functools
+
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -88,6 +94,12 @@ def data_parallel_step(
         p = jax.tree_util.tree_map_with_path(param_sharding, params)
         b = jax.tree_util.tree_map_with_path(batch_sharding, batch)
         return p, b
+
+    # keeps step_fn's name: the XLA module is named after it
+    @functools.wraps(step_fn)
+    def traced_on_mesh(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step_fn(*args)
 
     compiled: Dict[Any, Callable] = {}
 
@@ -113,7 +125,7 @@ def data_parallel_step(
             in_shardings = make_in_shardings(params, batch)
             extra = tuple(None for _ in args)
             fn = jax.jit(
-                step_fn,
+                traced_on_mesh,
                 in_shardings=(*in_shardings, *extra),
                 donate_argnums=(0,) if donate_params else (),
             )

@@ -13,7 +13,7 @@ from dmlc_core_tpu.models import (
     LogisticRegression,
 )
 from dmlc_core_tpu.ops import ell_matvec, ell_to_dense, weighted_mean
-from dmlc_core_tpu.parallel import data_parallel_step, make_mesh
+from dmlc_core_tpu.parallel import data_parallel_step, make_mesh, shard_params
 from dmlc_core_tpu.staging import BatchSpec, FixedShapeBatcher
 from dmlc_core_tpu.data.row_block import RowBlock
 
@@ -114,6 +114,90 @@ def test_fm_loss_decreases():
         b, _ = synth_batch(rng, batch=128, d=32, w_true=w_true)
         params, loss = step(params, b)
     assert float(loss) < first
+
+
+def fm_batches(rng, n, d, batch=16, dense=13, categorical=7):
+    """Criteo-layout ELL batches: ids 0..dense-1 in every row (the dense
+    fields), then categorical ids from a small range, so ids repeat
+    within and across rows."""
+    out = []
+    for _ in range(n):
+        cat = rng.integers(dense, d, size=(batch, categorical))
+        idx = np.concatenate(
+            [np.tile(np.arange(dense), (batch, 1)), cat], axis=1
+        ).astype(np.int32)
+        val = np.concatenate(
+            [rng.normal(size=(batch, dense)), np.ones((batch, categorical))],
+            axis=1,
+        ).astype(np.float32)
+        out.append({
+            "indices": idx,
+            "values": val,
+            "nnz": np.full(batch, dense + categorical, np.int32),
+            "labels": rng.integers(0, 2, batch).astype(np.float32),
+            "weights": np.ones(batch, np.float32),
+        })
+    return out
+
+
+def _dense_sgd(model, lr):
+    """p − lr·∇p with the gradient over the whole tables."""
+    return jax.jit(lambda p, b: jax.tree_util.tree_map(
+        lambda x, g: x - lr * g, p, jax.grad(model.loss)(p, b)))
+
+
+@pytest.mark.parametrize("l2,mesh_shape,path", [
+    (0.0, None, "sparse"),
+    (1e-3, None, "dense"),
+    (0.0, (2, 2), "sparse-replicated"),
+], ids=["sparse", "l2_dense", "mesh_2x2"])
+def test_fm_sgd_step_update_paths(l2, mesh_shape, path):
+    from dmlc_core_tpu.telemetry import default_registry
+
+    lr = 0.5
+    d = 128
+    model = FactorizationMachine(num_features=d, embed_dim=8, l2=l2)
+    params = model.init(jax.random.PRNGKey(7))
+    batches = fm_batches(np.random.default_rng(8), 3, d)
+    if mesh_shape is None:
+        ref_step = _dense_sgd(model, lr)
+    else:  # the mesh step against the one-device step
+        ref_step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=lr)[0])
+    ref = params
+    for b in batches:
+        ref = ref_step(ref, b)
+    ref = jax.device_get(ref)
+
+    view = default_registry().scoped("models.fm.")
+    train = lambda p, b: model.sgd_step(p, b, lr=lr)  # noqa: E731
+    if mesh_shape is None:
+        step = jax.jit(train)
+    else:
+        mesh = make_mesh(mesh_shape, ("data", "model"),
+                         devices=jax.devices("cpu")[:4])
+        rules = {"v": P(None, "model")}
+        step = data_parallel_step(train, mesh, donate_params=False,
+                                  param_rules=rules)
+        # placed as the step leaves them, so the step traces once
+        params = shard_params(params, mesh, rules)
+    got = params
+    for b in batches:
+        got, _loss = step(got, b)
+    ticks = {k: v for k, v in view.delta().items() if v}
+    assert ticks == {f'models.fm.update_path{{path="{path}"}}': 1.0}
+    for k in ("w", "v", "b"):
+        if path == "dense":  # the l2 step is exactly the dense formula
+            np.testing.assert_array_equal(np.asarray(got[k]), ref[k])
+        else:
+            np.testing.assert_allclose(np.asarray(got[k]), ref[k],
+                                       rtol=0, atol=1e-6)
+    # rows no batch touched keep their init on the sparse paths
+    untouched = np.setdiff1d(np.arange(d),
+                             np.concatenate([b["indices"] for b in batches]))
+    assert untouched.size
+    if path != "dense":
+        np.testing.assert_array_equal(np.asarray(got["v"])[untouched],
+                                      np.asarray(params["v"])[untouched])
 
 
 def test_dense_layout_forward_matches_ell():

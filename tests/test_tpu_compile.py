@@ -13,6 +13,8 @@ batch 8192, f16 values, int32 indices (what ``BatchSpec`` stages), and an
 FM of 2^24 features x E=16.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,25 +100,53 @@ def test_unpacker_compiles_for_one_chip(one_chip, record_property):
           f"temp {mem.temp_size_in_bytes} B")
 
 
-def test_fm_sgd_step_fits_one_chip(one_chip, record_property):
+def _ops(text, op):
+    """(dtype, dims) of every ``op`` (or its async ``-start``) result."""
+    return [
+        (dt, tuple(int(x) for x in dims.split(",") if x))
+        for dt, dims in re.findall(
+            r"= (\w+)\[([\d,]*)\]\S* " + op + r"(?:-start)?\(", text)
+    ]
+
+
+@pytest.fixture(scope="module")
+def fm_one_chip(one_chip):
     model = FactorizationMachine(N_FEATURES, embed_dim=EMBED)
     params = _fm_structs(model, lambda _name: one_chip)
     batch = _criteo_batch_structs(lambda _shape: one_chip)
     step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=0.1))
-    mem = step.lower(params, batch).compile().memory_analysis()
+    return step.lower(params, batch).compile()
+
+
+def test_fm_sgd_step_fits_one_chip(fm_one_chip, record_property):
+    mem = fm_one_chip.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     record_property("fm_step_bytes", int(total))
+    record_property("fm_step_temp_bytes", int(mem.temp_size_in_bytes))
     print(f"FM sgd_step 2^24 x {EMBED}: argument "
           f"{mem.argument_size_in_bytes} output {mem.output_size_in_bytes} "
           f"temp {mem.temp_size_in_bytes} B")
     assert total < HBM_BYTES, mem
 
 
-def test_sharded_unpack_and_spmd_step_compile_on_four_chips(topo):
-    from dmlc_core_tpu.parallel import data_parallel_step, make_mesh
+def test_fm_sgd_step_builds_no_table_gradient(fm_one_chip):
+    # the sparse update scatters row gradients into the table: no zeroed
+    # table-sized gradient (a broadcast of the table's shape) is built
+    text = fm_one_chip.as_text()
+    assert ("f32", (N_FEATURES, EMBED)) not in _ops(text, "broadcast")
+    assert ("f32", (N_FEATURES,)) not in _ops(text, "broadcast")
 
-    mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices[:4])
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from dmlc_core_tpu.parallel import make_mesh
+
+    return make_mesh((2, 2), ("data", "model"), devices=topo.devices[:4])
+
+
+def test_sharded_unpack_compiles_on_four_chips(mesh_2x2):
+    mesh = mesh_2x2
     n_shards = mesh.shape["data"]
     shard_entries, stride = packed_shard_layout(
         [(n, s, np.dtype(d).name) for n, s, d in SECTIONS], n_shards
@@ -132,6 +162,12 @@ def test_sharded_unpack_and_spmd_step_compile_on_four_chips(topo):
                "collective-permute"):
         assert op not in text, op
 
+
+@pytest.fixture(scope="module")
+def fm_2x2(mesh_2x2):
+    from dmlc_core_tpu.parallel import data_parallel_step
+
+    mesh = mesh_2x2
     model = FactorizationMachine(N_FEATURES, embed_dim=EMBED)
     rules = {"v": P(None, "model")}
     params = _fm_structs(
@@ -143,8 +179,34 @@ def test_sharded_unpack_and_spmd_step_compile_on_four_chips(topo):
     step = data_parallel_step(
         lambda p, b: model.sgd_step(p, b, lr=0.1), mesh, param_rules=rules,
     )
-    compiled = step.jitted(params, batch).lower(params, batch).compile()
-    mem = compiled.memory_analysis()
+    return step.jitted(params, batch).lower(params, batch).compile()
+
+
+def test_spmd_fm_step_fits_four_chips(fm_2x2):
+    mem = fm_2x2.memory_analysis()
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
     assert per_device < HBM_BYTES, mem
+
+
+def test_spmd_fm_step_all_reduces_no_table(fm_2x2):
+    # the row gradients are replicated before the scatter, so the
+    # partitioner all-gathers rows instead of all-reducing a table-sized
+    # gradient over 'data'
+    text = fm_2x2.as_text()
+    reduced = _ops(text, "all-reduce")
+    assert all(int(np.prod(dims)) < N_FEATURES for _dt, dims in reduced), (
+        reduced
+    )
+    assert _ops(text, "all-gather")
+
+
+def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
+    # the loss comes out of an all-reduce over the whole mesh scheduled
+    # after the scatters, so a caller that waits on it waits for every
+    # device's update
+    entry = fm_2x2.as_text().split("\nENTRY ", 1)[1].splitlines()
+    scatters = [i for i, ln in enumerate(entry) if "scatter-add" in ln]
+    whole = [i for i, ln in enumerate(entry)
+             if " all-reduce(" in ln and "replica_groups={{0,1,2,3}}" in ln]
+    assert scatters and whole and whole[-1] > scatters[-1]
