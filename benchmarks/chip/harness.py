@@ -5,13 +5,28 @@ One run of one cell: ``run.py --workload <name> --seed <n> --seconds <s>
 mix or per-layer metric lives in files of its own, found by name:
 
 - ``configs/<config>/config.json``: sizes, source, ``reduced``,
-  ``assumed``, mesh, limits of the ``correct`` comparison. Its code
-  (``program.py``: the system under test; ``generate.py``: the corpus;
-  ``reference.py``: the plain readers and the plain model; ``work.py``:
-  required FLOPs and bytes) is looked up in the config's directory first,
-  then in ``configs/<code>/`` when the config names one.
+  ``assumed``, mesh, limits of the ``correct`` comparison. Its other
+  files (``program.py``: the system under test; ``generate.py``: the
+  corpus; ``reference.py``: the plain readers and the plain model;
+  ``work.py``: required FLOPs and bytes; ``tiny.json``: the CPU tests'
+  sizes, ``{"config": {...}, "traffic": {...}}``) are looked up by
+  ``Cell.find``: in the config's directory first, then in
+  ``configs/<code>/`` when the config names one.
 - ``traffic/<traffic>.json``: read mode, format, batch, dtype, shuffle.
-- ``metrics/<metric>.py``: ``read(record) -> float | None``.
+- ``metrics/<metric>.py``: ``read(record) -> float | None``; nothing
+  when the record holds nothing to read.
+
+Adding to the benchmark edits no file that is there. A configuration is
+a new ``configs/<config>/`` holding ``config.json`` and either its own
+``program.py``, ``generate.py``, ``reference.py``, ``work.py`` and
+``tiny.json`` or a ``"code"`` key that borrows those of another config;
+a traffic mix is a new ``traffic/<traffic>.json``; a per-layer metric a
+new ``metrics/<metric>.py``. ``BENCHMARK.json`` then gains appended
+entries only: the ``configs`` entry, the ``workloads`` entry, the
+``per_layer`` entry, and the new cell's name appended to the
+``workloads`` list of every metric it reports. The CPU tests
+(``tests/bench_chip/``) read the cells from ``BENCHMARK.json`` and run
+each at its config's ``tiny.json`` sizes.
 
 A run: refuse any platform but the TPU, place the compile cache inside
 the checkout, check the native core, generate the corpus from the seed,
@@ -77,17 +92,21 @@ class Cell:
     def config_file(self, cfg_name: str, fname: str) -> str:
         return os.path.join(self.bench_dir, "configs", cfg_name, fname)
 
+    def find(self, fname: str) -> str:
+        """``configs/<config>/<fname>``, else ``configs/<code>/<fname>``."""
+        dirs = [self.cfg_name] + ([self.cfg["code"]] if "code" in self.cfg else [])
+        for d in dirs:
+            path = self.config_file(d, fname)
+            if os.path.exists(path):
+                return path
+        raise SystemExit(f"bench: config {self.cfg_name!r} has no {fname}")
+
     def module(self, name: str):
-        """``configs/<config>/<name>.py``, else ``configs/<code>/<name>.py``."""
+        """The config's ``<name>.py`` as ``find`` locates it."""
         if name not in self._modules:
-            dirs = [self.cfg_name] + ([self.cfg["code"]] if "code" in self.cfg else [])
-            for d in dirs:
-                path = self.config_file(d, name + ".py")
-                if os.path.exists(path):
-                    self._modules[name] = _load(path, f"bench_{d}_{name}")
-                    break
-            else:
-                raise SystemExit(f"bench: config {self.cfg_name!r} has no {name}.py")
+            path = self.find(name + ".py")
+            d = os.path.basename(os.path.dirname(path))
+            self._modules[name] = _load(path, f"bench_{d}_{name}")
         return self._modules[name]
 
     def metric_names(self, trace: bool) -> List[str]:
@@ -490,6 +509,10 @@ class Run:
             f"{win['boundaries']} epoch boundaries, {win['boundary_s']:.3f} s")
         log("staging: " + json.dumps(self.feed.staging[-1] if self.feed.staging
                                      else {}, sort_keys=True))
+        from dmlc_core_tpu.telemetry import default_registry
+
+        log("counters: " + json.dumps(
+            default_registry().counter_values(prefix="models."), sort_keys=True))
         checks = self.check()
         dev = self.devices[0]
         device = {"platform": dev.platform, "kind": dev.device_kind,

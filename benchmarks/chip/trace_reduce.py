@@ -16,7 +16,10 @@ writes, read with ``jax.profiler.ProfileData`` (nothing else). Output:
 - ``idle_gaps``: ``[label, seconds]`` of the longest idle gaps of any
   device, each named by the innermost ``bench:*`` or ``dmlc:*`` span of
   the window's own thread that holds the gap's midpoint (``other`` when
-  none does); ``idle_by_label`` sums every gap by the same label.
+  none does); ``idle_by_label`` sums every gap by the same label;
+- ``spans``, ``idle_overlap`` (``reduce_dir`` only): every host
+  thread's ``bench:*``/``dmlc:*`` span seconds and their overlap with
+  device idle, as ``thread_spans.reduce_threads`` gives them.
 
 Host and device events share the trace's clock (the profiler aligns
 them; tests/bench_chip checks the reduction on a trace recorded on the
@@ -47,9 +50,16 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def reduce_dir(trace_dir: str, n_devices: int = 0) -> dict:
+    """``reduce`` of the trace, with ``thread_spans.reduce_threads``'s
+    ``spans`` and ``idle_overlap`` (every host thread) merged in."""
     from jax.profiler import ProfileData
 
-    return reduce(ProfileData.from_file(find_xplane(trace_dir)), n_devices)
+    from thread_spans import reduce_threads  # it imports this module
+
+    pd = ProfileData.from_file(find_xplane(trace_dir))
+    out = reduce(pd, n_devices)
+    out.update(reduce_threads(pd, n_devices))
+    return out
 
 
 def _events(line) -> List[Tuple[float, float, str]]:

@@ -5,20 +5,21 @@ readers."""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
 
 import numpy as np
 import pytest
-from bench_chip_helpers import BENCH, REPO, run_tiny, tiny
+from bench_chip_helpers import BENCH, REPO, cells, run_tiny, tiny
 
 import harness
 
 pytestmark = pytest.mark.jax
 
-CELLS = ["criteo_fm.seq", "higgs_logreg.csv", "criteo_fm.shuffle",
-         "criteo_fm_2x2.seq"]
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+CONFIG_FILES = ("program.py", "generate.py", "reference.py", "work.py", "tiny.json")
 
 
 def test_command_refuses_the_cpu_and_names_it():
@@ -35,14 +36,31 @@ def test_command_refuses_the_cpu_and_names_it():
 
 
 def test_benchmark_json_names_files_that_exist():
+    """Checks that hold for every entry, so that an appended config, cell
+    or metric is held to them with no edit here."""
     spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    for c in spec["configs"]:
+    configs = {c["name"]: c for c in spec["configs"]}
+    names = [w["name"] for w in spec["workloads"]]
+    assert names == cells()
+    for group in (list(configs), names):
+        assert len(set(group)) == len(group)
+        assert all(NAME.fullmatch(n) for n in group), group
+    for c in configs.values():
         assert os.path.exists(os.path.join(REPO, c["file"]))
     for w in spec["workloads"]:
+        assert w["config"] in configs
         assert os.path.exists(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+        cell = harness.Cell(BENCH, w["name"])
+        for fname in CONFIG_FILES:
+            assert os.path.exists(cell.find(fname))
+    assert {w["config"] for w in spec["workloads"]} == set(configs)
     for m in spec["per_layer"]:
         assert os.path.exists(os.path.join(BENCH, "metrics", m["name"] + ".py"))
-    assert [w["name"] for w in spec["workloads"]] == CELLS
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert set(m.get("workloads", [])) <= set(names), m["name"]
+    four = sum(1 for w in spec["workloads"] if w["chips"] == 4)
+    assert {w["chips"] for w in spec["workloads"]} <= {1, 4}
+    assert four <= max(1, len(names) // 2)
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -65,9 +83,12 @@ def test_last_line_has_the_contract_keys(trace):
     json.dumps(out)
 
 
-def test_config_traffic_and_metric_are_found_by_name(tmp_path):
-    """A config, a traffic and a metric added as new files in a copy are
-    run with no edit to any file that was there."""
+@pytest.mark.parametrize("own_tiny", [False, True], ids=["borrowed", "own"])
+def test_config_traffic_and_metric_are_found_by_name(tmp_path, own_tiny):
+    """A config (its code borrowed through ``"code"``, its tiny sizes
+    borrowed or its own), a traffic, a cell and a metric added as new
+    files and appended entries in a copy are run with no edit to any file
+    that was there."""
     copy = tmp_path / "benchmarks" / "chip"
     shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(REPO, "native"), tmp_path / "native")
@@ -75,31 +96,42 @@ def test_config_traffic_and_metric_are_found_by_name(tmp_path):
               for p in _files(copy)}
     (copy / "configs" / "tiny_fm").mkdir()
     cfg = json.load(open(os.path.join(BENCH, "configs", "criteo_fm", "config.json")))
-    cfg.update({"code": "criteo_fm", "rows": 2048, "n_features": 1 << 10,
-                "embed_dim": 8})
+    cfg.update({"code": "criteo_fm", "embed_dim": 8})
     (copy / "configs" / "tiny_fm" / "config.json").write_text(json.dumps(cfg))
+    own = {"config": {"rows": 2048, "n_features": 1 << 10},
+           "traffic": {"batch": 128}}
+    if own_tiny:
+        (copy / "configs" / "tiny_fm" / "tiny.json").write_text(json.dumps(own))
     traffic = json.load(open(os.path.join(BENCH, "traffic", "seq.json")))
-    traffic["batch"] = 128
     (copy / "traffic" / "tiny_seq.json").write_text(json.dumps(traffic))
     (copy / "metrics" / "batches_seen.py").write_text(
         "def read(r):\n    return float(r['batches'])\n")
     spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    spec["configs"].append({"name": "tiny_fm", "source": "t",
+                            "file": "benchmarks/chip/configs/tiny_fm/config.json",
+                            "reduced": [], "why": "t"})
     spec["workloads"].append({"name": "tiny_fm.seq", "config": "tiny_fm",
                               "traffic": "tiny_seq", "chips": 1, "why": "t"})
     spec["per_layer"].append({"name": "batches_seen", "unit": "batches",
                               "better": "higher", "source": "program_span",
                               "layer": "dispatch and host to HBM",
-                              "moves": "rows_per_s"})
+                              "moves": "rows_per_s", "workloads": []})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("tiny_fm.seq")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    from bench_chip_helpers import CANNED_TRACE
 
-    out = harness.run("tiny_fm.seq", 3, 0.5, True, platform="cpu",
-                      bench_dir=str(copy),
-                      hooks={"reduce": lambda _d: CANNED_TRACE,
-                             "device_kind": "TPU v5 lite"})
-    assert out["correct"] is True
-    assert out["metrics"]["batches_seen"]["value"] > 0
-    assert out["metrics"]["batches_seen"]["unit"] == "batches"
+    assert cells(str(tmp_path))[-1] == "tiny_fm.seq"
+    sizes = tiny("tiny_fm.seq", str(copy))
+    assert sizes == (own if own_tiny else tiny("criteo_fm.seq"))
+    untraced = run_tiny("tiny_fm.seq", seed=3, bench_dir=str(copy))
+    assert untraced["correct"] is True, untraced["checks"]
+    assert set(untraced["metrics"]) == {m["name"] for m in spec["end_to_end"]}
+    traced = run_tiny("tiny_fm.seq", seed=4, trace=True, bench_dir=str(copy))
+    assert traced["correct"] is True, traced["checks"]
+    assert set(traced["metrics"]) == {m["name"] for m in spec["per_layer"]}
+    assert traced["metrics"]["batches_seen"]["value"] > 0
+    assert traced["metrics"]["batches_seen"]["unit"] == "batches"
     after = {p: open(os.path.join(copy, p), "rb").read() for p in before}
     assert after == before
 
@@ -111,8 +143,7 @@ def _files(root):
                 yield os.path.relpath(os.path.join(d, f), root)
 
 
-@pytest.mark.parametrize("workload", ["criteo_fm.seq", "criteo_fm.shuffle",
-                                      "higgs_logreg.csv"])
+@pytest.mark.parametrize("workload", cells())
 def test_generators_give_the_same_batches_fused_and_plain(workload, tmp_path):
     """Two whole epochs through the fused producers equal the plain
     readers' batches bit for bit."""
@@ -139,7 +170,7 @@ def test_generators_give_the_same_batches_fused_and_plain(workload, tmp_path):
         for k in b:
             assert b[k].dtype == want[key][k].dtype, (key, k)
             assert b[k].tobytes() == want[key][k].tobytes(), (key, k)
-    if workload == "criteo_fm.shuffle":
+    if traffic["read"] == "shuffle":
         assert got[(0, 0)]["indices"].tobytes() != got[(1, 0)]["indices"].tobytes()
 
 
@@ -182,7 +213,7 @@ def test_same_seed_same_corpus(tmp_path):
     assert open(a, "rb").read() == open(b, "rb").read()
 
 
-@pytest.mark.parametrize("workload", CELLS[1:])
+@pytest.mark.parametrize("workload", cells())
 def test_each_cell_runs_correct_on_the_cpu(workload):
     out = run_tiny(workload)
     assert out["correct"] is True, out["checks"]
@@ -191,3 +222,12 @@ def test_each_cell_runs_correct_on_the_cpu(workload):
     want = {m["name"] for m in spec["end_to_end"]
             if workload in m.get("workloads", [workload])}
     assert set(out["metrics"]) == want
+
+
+def test_the_run_log_prints_the_model_counters(capsys):
+    out = run_tiny("criteo_fm.seq")
+    assert out["correct"] is True, out["checks"]
+    (line,) = [ln for ln in capsys.readouterr().err.splitlines()
+               if ln.startswith("[bench] counters: ")]
+    counters = json.loads(line.split(": ", 1)[1])
+    assert counters['models.fm.update_path{path="sparse"}'] >= 1

@@ -8,6 +8,7 @@ ops and of the named spans, counting how many of each are open on each
 elementary segment (apart from this code)."""
 
 import os
+import shutil
 
 import pytest
 from bench_chip_helpers import BENCH
@@ -111,6 +112,22 @@ def test_new_spans_against_idle(new):
             "count": count, "threads": threads}
         assert out["idle_overlap"][name] == pytest.approx(idle_ns * 1e-9,
                                                           rel=1e-12)
+
+
+def test_reduce_dir_carries_every_threads_spans(new, tmp_path):
+    """The harness's reduction of a trace directory holds the thread keys
+    beside its own, so every reader of them finds something to read."""
+    pd, _lines = new
+    shutil.copy(THREADS, tmp_path / "t.xplane.pb")
+    tr = harness._load(os.path.join(BENCH, "trace_reduce.py"), "bench_trace_reduce")
+    red = tr.reduce_dir(str(tmp_path), 1)
+    assert {k: red[k] for k in ("spans", "idle_overlap")} == (
+        thread_spans.reduce_threads(pd, 1))
+    assert {k: v for k, v in red.items()
+            if k not in ("spans", "idle_overlap")} == tr.reduce(pd, 1)
+    record = {"batches": 40, "dispatched": 41, "trace": red}
+    for name in WANT:
+        assert harness.metric_reader(BENCH, name)(record) > 0, name
 
 
 def test_a_name_on_two_lines_is_unioned_before_the_idle():

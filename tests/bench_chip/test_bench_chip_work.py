@@ -6,12 +6,9 @@ import os
 
 import numpy as np
 import pytest
-from bench_chip_helpers import BENCH
+from bench_chip_helpers import BENCH, cells
 
 import harness
-
-CELLS = ["criteo_fm.seq", "higgs_logreg.csv", "criteo_fm.shuffle",
-         "criteo_fm_2x2.seq"]
 
 
 def _work(workload):
@@ -40,7 +37,7 @@ def test_logreg_step_work_hand_counted():
     assert w["bytes"] == 48 + 32 + 32
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", cells())
 def test_unpack_bytes_are_one_devices_arrays_in_and_out(workload, tmp_path):
     """At the timed shapes: what one device's unpack must read and write
     is its share of the staged arrays, once in and once out."""
@@ -99,7 +96,7 @@ def _read(name, record):
     return harness.metric_reader(BENCH, name)(record)
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", cells())
 def test_shares_reach_100_only_at_the_roofline(workload):
     step, unpack = _t_min(workload)
     # every execution at its least possible time: the unpack at 100%; the
