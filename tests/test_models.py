@@ -146,20 +146,84 @@ def _dense_sgd(model, lr):
         lambda x, g: x - lr * g, p, jax.grad(model.loss)(p, b)))
 
 
-@pytest.mark.parametrize("l2,mesh_shape,path", [
-    (0.0, None, "sparse"),
-    (1e-3, None, "dense"),
-    (0.0, (2, 2), "sparse-replicated"),
-], ids=["sparse", "l2_dense", "mesh_2x2"])
-def test_fm_sgd_step_update_paths(l2, mesh_shape, path):
+def _with_ids(rng, batches, make_ids):
+    """``batches`` with their indices replaced by ``make_ids(rng, shape)``."""
+    return [dict(b, indices=make_ids(rng, b["indices"].shape).astype(np.int32))
+            for b in batches]
+
+
+def _distinct(n):
+    """Ids 0..n-1 each at least once, the other slots on them at random."""
+    def make(rng, shape):
+        ids = np.concatenate([rng.permutation(n),
+                              rng.integers(0, n, int(np.prod(shape)) - n)])
+        return rng.permutation(ids).reshape(shape)
+    return make
+
+
+def _zipf_fields(rng, shape):
+    """Criteo-like rows over tiny per-field vocabularies, Zipf(0.99) ranks
+    in each field's range (the dense ids 0..12 in every row)."""
+    vocab = [2, 3, 5, 7, 40, 60]
+    out = np.tile(np.arange(shape[1]), (shape[0], 1))
+    off = 13
+    for f, n in zip(range(13, shape[1]), vocab * 2):
+        p = np.arange(1, n + 1, dtype=np.float64) ** -0.99
+        r = rng.choice(n, size=shape[0], p=p / p.sum())
+        out[:, f] = off + r
+        off += n
+    return out
+
+
+# batch shape 16 x 20 (fm_batches): 320 slots, merged scatter sizes in
+# steps of 40 up to 120; above 120 distinct ids the slots are scattered
+FM_IDS = {
+    "criteo": None,
+    # one row takes all 320 slots' gradients: at lr 0.5 its three steps
+    # are large enough that float32 rounding of the sums, in any order,
+    # grows past 1e-6 (against float64, after three steps: the dense
+    # formula 8.8e-7, the merged sums 2.7e-6, one scatter row per slot
+    # 1.9e-5), so this case steps at a tenth of that
+    "one_id": lambda rng, shape: np.full(shape, 5),
+    "three_ids": lambda rng, shape: np.where(
+        rng.uniform(size=shape) < 0.5, rng.choice([7, 40, 99], size=shape),
+        rng.integers(0, 120, size=shape)),
+    "zipf": _zipf_fields,
+    "bucket_edge": _distinct(80),
+    "over_bucket_edge": _distinct(81),
+    "largest_merge": _distinct(120),
+    "over_largest_merge": _distinct(121),
+}
+
+
+@pytest.mark.parametrize("l2,mesh_shape,path,ids", [
+    (0.0, None, "sparse", "criteo"),
+    (1e-3, None, "dense", "criteo"),
+    (0.0, (2, 2), "sparse-replicated", "criteo"),
+    (0.0, None, "sparse", "one_id"),
+    (0.0, None, "sparse", "three_ids"),
+    (0.0, None, "sparse", "zipf"),
+    (0.0, None, "sparse", "bucket_edge"),
+    (0.0, None, "sparse", "over_bucket_edge"),
+    (0.0, None, "sparse", "largest_merge"),
+    (0.0, None, "sparse", "over_largest_merge"),
+    (0.0, (2, 2), "sparse-replicated", "zipf"),
+], ids=["sparse", "l2_dense", "mesh_2x2", "one_id", "three_ids", "zipf",
+        "bucket_edge", "over_bucket_edge", "largest_merge",
+        "over_largest_merge", "mesh_2x2_zipf"])
+def test_fm_sgd_step_update_paths(l2, mesh_shape, path, ids):
+    from dmlc_core_tpu.models import fm
     from dmlc_core_tpu.telemetry import default_registry
 
-    lr = 0.5
+    lr = 0.05 if ids == "one_id" else 0.5
     d = 128
     model = FactorizationMachine(num_features=d, embed_dim=8, l2=l2)
     params = model.init(jax.random.PRNGKey(7))
-    batches = fm_batches(np.random.default_rng(8), 3, d)
-    if mesh_shape is None:
+    rng = np.random.default_rng(8)
+    batches = fm_batches(rng, 3, d)
+    if FM_IDS[ids] is not None:
+        batches = _with_ids(rng, batches, FM_IDS[ids])
+    if mesh_shape is None or ids != "criteo":
         ref_step = _dense_sgd(model, lr)
     else:  # the mesh step against the one-device step
         ref_step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=lr)[0])
@@ -184,7 +248,11 @@ def test_fm_sgd_step_update_paths(l2, mesh_shape, path):
     for b in batches:
         got, _loss = step(got, b)
     ticks = {k: v for k, v in view.delta().items() if v}
-    assert ticks == {f'models.fm.update_path{{path="{path}"}}': 1.0}
+    want = {f'models.fm.update_path{{path="{path}"}}': 1.0}
+    if path != "dense":
+        # the merged sizes 40, 80, 120 and the slots as they are
+        want["models.fm.dedup_branches"] = float(fm.MERGED_EIGHTHS + 1)
+    assert ticks == want
     for k in ("w", "v", "b"):
         if path == "dense":  # the l2 step is exactly the dense formula
             np.testing.assert_array_equal(np.asarray(got[k]), ref[k])
@@ -196,8 +264,9 @@ def test_fm_sgd_step_update_paths(l2, mesh_shape, path):
                              np.concatenate([b["indices"] for b in batches]))
     assert untouched.size
     if path != "dense":
-        np.testing.assert_array_equal(np.asarray(got["v"])[untouched],
-                                      np.asarray(params["v"])[untouched])
+        for k in ("w", "v"):
+            np.testing.assert_array_equal(np.asarray(got[k])[untouched],
+                                          np.asarray(params[k])[untouched])
 
 
 def test_dense_layout_forward_matches_ell():

@@ -201,12 +201,34 @@ def test_spmd_fm_step_all_reduces_no_table(fm_2x2):
     assert _ops(text, "all-gather")
 
 
+def _computation(text, name):
+    """The body of the HLO computation ``name`` in ``text``."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines)
+                 if ln.startswith((f"%{name} ", f"{name} ")))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return "\n".join(lines[start:end])
+
+
+def _updates(text, line):
+    """Whether an entry line scatters into the tables: a scatter itself,
+    or a conditional (the scatter size chosen on the device) with a
+    scatter in its branches."""
+    if "scatter-add" in line:
+        return True
+    m = re.search(r"branch_computations=\{([^}]*)\}", line)
+    return bool(m) and all(
+        "scatter-add" in _computation(text, b.strip().lstrip("%"))
+        for b in m.group(1).split(","))
+
+
 def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
     # the loss comes out of an all-reduce over the whole mesh scheduled
     # after the scatters, so a caller that waits on it waits for every
     # device's update
-    entry = fm_2x2.as_text().split("\nENTRY ", 1)[1].splitlines()
-    scatters = [i for i, ln in enumerate(entry) if "scatter-add" in ln]
+    text = fm_2x2.as_text()
+    entry = text.split("\nENTRY ", 1)[1].splitlines()
+    scatters = [i for i, ln in enumerate(entry) if _updates(text, ln)]
     whole = [i for i, ln in enumerate(entry)
              if " all-reduce(" in ln and "replica_groups={{0,1,2,3}}" in ln]
     assert scatters and whole and whole[-1] > scatters[-1]
