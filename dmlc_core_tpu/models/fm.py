@@ -7,8 +7,11 @@ embedding table is the natural tensor-parallel shard target: split the E
 axis over the mesh's 'model' axis (see parallel/ and __graft_entry__).
 
 ``sgd_step`` updates only the rows a batch touches when the loss has no
-l2 term (see its docstring), one scatter row per distinct id; with l2
-every row has a gradient and the step stays dense.
+l2 term (see its docstring); with l2 every row has a gradient and the
+step stays dense. On one TPU chip a Pallas kernel writes the new ``v`` in
+one streaming pass, adding every slot's row gradient on the way
+(``_add_rows``); elsewhere, and on a mesh, XLA scatters the rows, merging
+duplicate ids first when a batch has few distinct ids (``_update_rows``).
 """
 
 from __future__ import annotations
@@ -18,6 +21,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.sparse import weighted_mean
 from ..telemetry.registry import default_registry
@@ -34,20 +39,22 @@ def _count_update_path(path: str) -> None:
     default_registry().counter(
         "models.fm.update_path",
         help="FM sgd_step traces by update path "
-        "(sparse | sparse-replicated | dense)",
+        "(sparse-kernel | sparse | sparse-replicated | dense)",
         labels={"path": path},
     ).inc()
 
 
-# A scatter row costs about the same whatever its width and whether or
-# not its id repeats (≈100 ns on a v5e), so a batch with few distinct ids
-# merges its duplicates and scatters one row per distinct id, in the
-# smallest of the static sizes 1/8, 2/8, .. MERGED_EIGHTHS/8 of its slots
-# that holds them (chosen on the device). Merging costs gathers of the
-# row gradients in id order and a scan over them; with more distinct ids
-# it costs more than the rows it saves (at 2/3 distinct, 6 ms a step more
-# than the 32 ms scatter it replaces on a v5e), and the slots are
-# scattered as they are.
+# The merge serves XLA's scatter only: the mesh path, and devices other
+# than a TPU (one TPU chip takes the kernel, ``_add_rows``). A scatter
+# row costs about the same whatever its width and whether or not its id
+# repeats (≈100 ns on a v5e), so a batch with few distinct ids merges
+# its duplicates and scatters one row per distinct id, in the smallest of
+# the static sizes 1/8, 2/8, .. MERGED_EIGHTHS/8 of its slots that holds
+# them (chosen on the device). Merging costs gathers of the row gradients
+# in id order and a scan over them; with more distinct ids it costs more
+# than the rows it saves (at 2/3 distinct, 6 ms a step more than the
+# 32 ms scatter it replaces on a v5e), and the slots are scattered as
+# they are.
 MERGED_EIGHTHS = 3
 
 
@@ -122,6 +129,150 @@ def _update_rows(w, v, ids, gw, gv, lr: float):
         jnp.searchsorted(jnp.asarray(sizes, jnp.int32), n),
         branches, w, v, ids, gw, gv, sid, perm, differs,
     )
+
+
+# The kernel's table block: table rows (columns of the [E, N] view) that
+# one grid step copies to its output and updates (PERF.md §6).
+KERNEL_COLS = 8192
+_LANES = 128
+# Sorted ids and their slots come into SMEM (1 MiB) this many at a time.
+_ID_CHUNK = 1024
+# Slots whose rows the kernel loads and rolls side by side.
+_UNROLL = 4
+# The [E, S] row gradients stay whole in VMEM (128 MiB on a v5e).
+_ROWS_VMEM_BYTES = 32 << 20
+
+
+def _update_path(platform: str, mesh, l2: float, embed_dim: int,
+                 slots: int) -> str:
+    """Which update ``sgd_step`` compiles to. The kernel needs a TPU with
+    no mesh, no l2, a table that XLA lays out E-minor-major (E < 128, so
+    its [E, N] view is a bitcast) and row gradients that fit VMEM."""
+    if l2:
+        return "dense"
+    if not mesh.empty:
+        return "sparse-replicated"
+    padded = -(-slots // _LANES) * _LANES
+    if (platform == "tpu" and embed_dim < _LANES
+            and padded * embed_dim * 4 <= _ROWS_VMEM_BYTES):
+        return "sparse-kernel"
+    return "sparse"
+
+
+def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
+                     out_ref, sid_buf, perm_buf, loaded, sem):
+    """One block of ``KERNEL_COLS`` table rows: copy it, then add the row
+    of every slot whose sorted id falls in it (``starts`` bounds the
+    block's sorted slots), one lane of a 128-row tile at a time."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        loaded[0] = -1
+
+    out_ref[...] = table_ref[...]
+    lo, hi = starts_ref[i], starts_ref[i + 1]
+    base = i * KERNEL_COLS
+    lane_of = jax.lax.broadcasted_iota(
+        jnp.int32, (out_ref.shape[0], _LANES), 1)
+
+    def row(j):
+        """Slot j's tile offset in the block, and its row on its lane."""
+        # both are ≥ 0, so masks stand for % and // (a signed % costs a
+        # dozen scalar ops a slot)
+        s = sid_buf[j] - base
+        p = perm_buf[j]
+        lane = s & (_LANES - 1)
+        # the slot's row is column p of the gradients: roll its tile so
+        # that the column lands on the lane of row s in the table's tile
+        g = rows_ref[:, pl.ds(pl.multiple_of(p & -_LANES, _LANES), _LANES)]
+        g = pltpu.roll(g, (lane - p) & (_LANES - 1), 1)
+        return (pl.multiple_of(s & -_LANES, _LANES),
+                jnp.where(lane_of == lane, g, 0.0))
+
+    def add(rows):
+        for at, g in rows:
+            out_ref[:, pl.ds(at, _LANES)] += g
+
+    def chunk(c, _):
+        first = c * _ID_CHUNK
+
+        @pl.when(loaded[0] != c)  # consecutive blocks share a chunk
+        def _():
+            ids = pltpu.make_async_copy(
+                sid_hbm.at[pl.ds(first, _ID_CHUNK)], sid_buf, sem.at[0])
+            slots = pltpu.make_async_copy(
+                perm_hbm.at[pl.ds(first, _ID_CHUNK)], perm_buf, sem.at[1])
+            ids.start()
+            slots.start()
+            ids.wait()
+            slots.wait()
+            loaded[0] = c
+
+        a = jnp.maximum(lo, first) - first
+        b = jnp.minimum(hi, first + _ID_CHUNK) - first
+        groups = (b - a) // _UNROLL
+        # a group's rows are loaded and rolled side by side before the
+        # adds, which stay in order (two may hit one tile)
+        jax.lax.fori_loop(0, groups, lambda t, _: add(
+            [row(a + t * _UNROLL + u) for u in range(_UNROLL)]), None)
+        jax.lax.fori_loop(a + groups * _UNROLL, b,
+                          lambda j, _: add([row(j)]), None)
+
+    last = jnp.where(hi > lo, (hi - 1) // _ID_CHUNK + 1, lo // _ID_CHUNK)
+    jax.lax.fori_loop(lo // _ID_CHUNK, last, chunk, None)
+
+
+def _add_rows(table_t, sid, perm, rows_t):
+    """``table_t`` [E, N] with column ``perm[k]`` of ``rows_t`` [E, S]
+    added at column ``sid[k]`` for every k (``sid`` ascending), in one
+    streaming pass that writes the whole new table; equal ids add one
+    after another. Ids outside [0, N) are dropped."""
+    e, n = table_t.shape
+    s = sid.shape[0]
+    rows_t = jnp.pad(rows_t, ((0, 0), (0, -(-s // _LANES) * _LANES - s)))
+    pad = -(-s // _ID_CHUNK) * _ID_CHUNK - s
+    blocks = pl.cdiv(n, KERNEL_COLS)
+    starts = jnp.searchsorted(
+        sid, KERNEL_COLS * jax.lax.iota(jnp.int32, blocks + 1)
+    ).astype(jnp.int32)
+    block = pl.BlockSpec((e, KERNEL_COLS), lambda i, _starts: (0, i))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _add_rows_kernel,
+        out_shape=jax.ShapeDtypeStruct(table_t.shape, table_t.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(blocks,),
+            in_specs=[hbm, hbm,
+                      pl.BlockSpec(rows_t.shape, lambda i, _starts: (0, 0),
+                                   pipeline_mode=pl.Buffered(1)),
+                      block],
+            out_specs=block,
+            scratch_shapes=[pltpu.SMEM((_ID_CHUNK,), jnp.int32),
+                            pltpu.SMEM((_ID_CHUNK,), jnp.int32),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the gradients, the double-buffered in and out blocks, slack
+            vmem_limit_bytes=rows_t.size * 4 + 16 * e * KERNEL_COLS
+            + (4 << 20),
+        ),
+        name="fm_add_rows",
+    )(starts, jnp.pad(sid, (0, pad)), jnp.pad(perm, (0, pad)), rows_t,
+      table_t)
+
+
+def _update_rows_kernel(w, v, ids, gw, gv, lr: float):
+    """``_update_rows`` on one TPU chip: XLA scatters every slot into
+    ``w`` (a scalar row costs 9–13 ns); the kernel writes the new ``v``
+    through the table's free [E, N] view."""
+    s = ids.shape[0]
+    sid, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, s)), num_keys=1)
+    return (w.at[ids].add(-lr * gw),
+            _add_rows(v.T, sid, perm, (-lr * gv).T).T)
 
 
 def _done_on_every_device(params: Params, loss: jax.Array, mesh):
@@ -221,11 +372,13 @@ class FactorizationMachine:
 
         With ``l2 == 0`` only the rows the batch gathers have a gradient,
         so the step differentiates with respect to the gathered rows and
-        scatter-adds −lr·(row gradient) into ``w`` and ``v`` at the
-        batch's ids (duplicate ids sum, as the dense gradient sums them;
-        a batch with few distinct ids sums them first and scatters one
-        row per id, see ``_update_rows``): no table-sized gradient is
-        built, zeroed or subtracted. Traced
+        adds −lr·(row gradient) into ``w`` and ``v`` at the batch's ids
+        (duplicate ids sum, as the dense gradient sums them): no
+        table-sized gradient is built, zeroed or subtracted. On one TPU
+        chip a kernel writes the new ``v`` with the rows added
+        (``_add_rows``); elsewhere XLA scatters them, and a batch with
+        few distinct ids sums them first and scatters one row per id
+        (``_update_rows``). Traced
         under a mesh (``parallel.data_parallel_step`` sets it), the ids
         and row gradients are first replicated over the mesh, so the
         partitioner all-gathers the rows instead of all-reducing a
@@ -234,8 +387,11 @@ class FactorizationMachine:
         ``_done_on_every_device``). With ``l2 > 0`` every row has a
         gradient and the step is the dense one.
         """
-        if self.l2:
-            _count_update_path("dense")
+        mesh = jax.sharding.get_abstract_mesh()
+        path = _update_path(jax.default_backend(), mesh, self.l2,
+                            self.embed_dim, batch["indices"].size)
+        _count_update_path(path)
+        if path == "dense":
             loss_val, grads = self.loss_and_grads(params, batch)
             return sgd_update(params, grads, lr), loss_val
         # K-major rows ([K, B, E]): the row gradient's minor dimension is
@@ -249,17 +405,15 @@ class FactorizationMachine:
         ids = idx.reshape(-1)
         gw = g["w"].reshape(-1)
         gv = g["v"].reshape(-1, self.embed_dim)
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh.empty:
-            _count_update_path("sparse")
-        else:
-            _count_update_path("sparse-replicated")
+        update = (_update_rows_kernel if path == "sparse-kernel"
+                  else _update_rows)
+        if path == "sparse-replicated":
             ids, gw, gv = jax.lax.with_sharding_constraint(
                 (ids, gw, gv), jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec()
                 ),
             )
-        w, v = _update_rows(params["w"], params["v"], ids, gw, gv, lr)
+        w, v = update(params["w"], params["v"], ids, gw, gv, lr)
         new = {"w": w, "v": v, "b": params["b"] - lr * g["b"]}
         if not mesh.empty:
             new, loss_val = _done_on_every_device(new, loss_val, mesh)

@@ -12,6 +12,7 @@ from dmlc_core_tpu.models import (
     LinearRegression,
     LogisticRegression,
 )
+from dmlc_core_tpu.models.fm import KERNEL_COLS
 from dmlc_core_tpu.ops import ell_matvec, ell_to_dense, weighted_mean
 from dmlc_core_tpu.parallel import data_parallel_step, make_mesh, shard_params
 from dmlc_core_tpu.staging import BatchSpec, FixedShapeBatcher
@@ -268,6 +269,123 @@ def test_fm_sgd_step_update_paths(l2, mesh_shape, path, ids):
             np.testing.assert_array_equal(np.asarray(got[k])[untouched],
                                           np.asarray(params[k])[untouched])
 
+
+
+# -- the one-chip row update kernel, in Pallas interpret mode ----------------
+
+def _hot_ids(rng, n, s):
+    """Zipf(0.99) ranks over the table, scrambled: a few ids take most
+    slots."""
+    p = np.arange(1, n + 1, dtype=np.float64) ** -0.99
+    return (rng.choice(n, size=s, p=p / p.sum()) * 7919) % n
+
+
+def _skip_block(rng, n, s):
+    """Ids in the first and third ``KERNEL_COLS`` blocks only."""
+    ids = rng.integers(0, KERNEL_COLS, size=s)
+    return np.where(rng.uniform(size=s) < 0.5, ids, ids + 2 * KERNEL_COLS)
+
+
+def _table_ends(rng, n, s):
+    ids = rng.integers(0, n, size=s)
+    ids[::7], ids[3::11] = 0, n - 1
+    return ids
+
+
+# name: (table rows, E, slots, ids(rng, rows, slots))
+KERNEL_CASES = {
+    "uniform": (2 * KERNEL_COLS, 16, 384,
+                lambda rng, n, s: rng.integers(0, n, size=s)),
+    "zipf": (2 * KERNEL_COLS, 16, 300, _hot_ids),
+    "ragged_table": (2 * KERNEL_COLS + 77, 16, 300,
+                     lambda rng, n, s: rng.integers(0, n, size=s)),
+    "empty_block": (3 * KERNEL_COLS, 16, 300, _skip_block),
+    "table_ends": (KERNEL_COLS + 77, 16, 300, _table_ends),
+    "embed_8": (KERNEL_COLS + 77, 8, 300, _table_ends),
+    # more slots than one SMEM chunk of ids: blocks that span chunks
+    "id_chunks": (3 * KERNEL_COLS + 77, 16, 2600,
+                  lambda rng, n, s: rng.integers(0, n, size=s)),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_fm_kernel_update_matches_scatter(case):
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dmlc_core_tpu.models import fm
+
+    n, e, s, make_ids = KERNEL_CASES[case]
+    rng = np.random.default_rng(11)
+    ids = make_ids(rng, n, s).astype(np.int32)
+    w = rng.normal(size=n).astype(np.float32)
+    v = rng.normal(size=(n, e)).astype(np.float32)
+    gw = rng.normal(size=s).astype(np.float32)
+    gv = rng.normal(size=(s, e)).astype(np.float32)
+    lr = 0.5
+    update = jax.jit(lambda *a: fm._update_rows_kernel(*a, lr=lr))
+    with pltpu.force_tpu_interpret_mode():
+        got_w, got_v = update(w, v, ids, gw, gv)
+    for got, table, rows in ((got_w, w, gw), (got_v, v, gv)):
+        want = np.asarray(jnp.asarray(table).at[ids].add(-lr * rows))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                                   atol=1e-6 * np.abs(want).max())
+    untouched = np.setdiff1d(np.arange(n), ids)
+    np.testing.assert_array_equal(np.asarray(got_v)[untouched], v[untouched])
+
+
+@pytest.mark.parametrize("platform,mesh_shape,l2,embed,slots,path", [
+    ("tpu", None, 0.0, 16, 8192 * 39, "sparse-kernel"),
+    ("tpu", None, 0.0, 8, 300, "sparse-kernel"),
+    ("cpu", None, 0.0, 16, 8192 * 39, "sparse"),
+    ("tpu", (2, 2), 0.0, 16, 8192 * 39, "sparse-replicated"),
+    ("tpu", None, 1e-3, 16, 8192 * 39, "dense"),
+    ("cpu", (2, 2), 1e-3, 16, 8192 * 39, "dense"),
+    ("tpu", None, 0.0, 128, 8192 * 39, "sparse"),
+    ("tpu", None, 0.0, 16, 1 << 20, "sparse"),
+], ids=["one_tpu", "one_tpu_small", "cpu", "mesh", "l2", "cpu_mesh_l2",
+        "embed_128", "rows_past_vmem"])
+def test_fm_update_path_rule(platform, mesh_shape, l2, embed, slots, path):
+    from dmlc_core_tpu.models import fm
+
+    if mesh_shape is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    else:
+        mesh = make_mesh(mesh_shape, ("data", "model"),
+                         devices=jax.devices("cpu")[:4]).abstract_mesh
+    assert fm._update_path(platform, mesh, l2, embed, slots) == path
+
+
+def test_fm_sgd_step_kernel_path(monkeypatch):
+    """The step on the kernel path (steered to it as on one TPU chip)
+    against the dense formula."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dmlc_core_tpu.models import fm
+    from dmlc_core_tpu.telemetry import default_registry
+
+    rule = fm._update_path
+    monkeypatch.setattr(fm, "_update_path",
+                        lambda _platform, *a: rule("tpu", *a))
+    lr, d = 0.5, 200
+    model = FactorizationMachine(num_features=d, embed_dim=8)
+    params = model.init(jax.random.PRNGKey(7))
+    rng = np.random.default_rng(8)
+    batches = _with_ids(rng, fm_batches(rng, 3, d), _zipf_fields)
+    ref_step = _dense_sgd(model, lr)
+    ref = params
+    for b in batches:
+        ref = ref_step(ref, b)
+    view = default_registry().scoped("models.fm.")
+    step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=lr))
+    got = params
+    with pltpu.force_tpu_interpret_mode():
+        for b in batches:
+            got, _loss = step(got, b)
+    ticks = {k: v for k, v in view.delta().items() if v}
+    assert ticks == {'models.fm.update_path{path="sparse-kernel"}': 1.0}
+    for k in ("w", "v", "b"):
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref[k]),
+                                   rtol=0, atol=1e-6)
 
 def test_dense_layout_forward_matches_ell():
     rng = np.random.default_rng(4)

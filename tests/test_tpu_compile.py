@@ -109,13 +109,24 @@ def _ops(text, op):
     ]
 
 
+def _on_one_tpu(mp):
+    """Steer the FM step to the update it takes on one TPU chip: the
+    process's backend is the CPU here."""
+    from dmlc_core_tpu.models import fm
+
+    rule = fm._update_path
+    mp.setattr(fm, "_update_path", lambda _platform, *a: rule("tpu", *a))
+
+
 @pytest.fixture(scope="module")
 def fm_one_chip(one_chip):
     model = FactorizationMachine(N_FEATURES, embed_dim=EMBED)
     params = _fm_structs(model, lambda _name: one_chip)
     batch = _criteo_batch_structs(lambda _shape: one_chip)
     step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=0.1))
-    return step.lower(params, batch).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        _on_one_tpu(mp)
+        return step.lower(params, batch).compile()
 
 
 def test_fm_sgd_step_fits_one_chip(fm_one_chip, record_property):
@@ -136,6 +147,56 @@ def test_fm_sgd_step_builds_no_table_gradient(fm_one_chip):
     text = fm_one_chip.as_text()
     assert ("f32", (N_FEATURES, EMBED)) not in _ops(text, "broadcast")
     assert ("f32", (N_FEATURES,)) not in _ops(text, "broadcast")
+
+
+def _kernel_calls(text):
+    """Result (dtype, dims) of every Pallas kernel (``tpu_custom_call``)."""
+    return [
+        (dt, tuple(int(x) for x in dims.split(",") if x))
+        for dt, dims, target in re.findall(
+            r"= (\w+)\[([\d,]*)\]\S* custom-call\(.*?"
+            r"custom_call_target=\"(\w+)\"", text)
+        if target == "tpu_custom_call"
+    ]
+
+
+def _table_copies(text, n):
+    """copy/transpose ops whose result is the [N, E] table or its view."""
+    tables = {("f32", (n, EMBED)), ("f32", (EMBED, n))}
+    return [r for op in ("copy", "transpose") for r in _ops(text, op)
+            if r in tables]
+
+
+def test_fm_sgd_step_writes_v_in_the_kernel(fm_one_chip):
+    # one TPU chip: the kernel writes the new v, and XLA copies no table
+    text = fm_one_chip.as_text()
+    assert _kernel_calls(text) == [("f32", (EMBED, N_FEATURES))]
+    assert not _table_copies(text, N_FEATURES)
+
+
+@pytest.mark.parametrize("n", [N_FEATURES, 33_762_590],
+                         ids=["seq_2e24", "zipf_kaggle"])
+def test_fm_kernel_update_compiles(one_chip, n, record_property):
+    from dmlc_core_tpu.models import fm
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = B * K
+    update = jax.jit(lambda *a: fm._update_rows_kernel(*a, lr=0.1))
+    compiled = update.lower(
+        struct((n,), jnp.float32), struct((n, EMBED), jnp.float32),
+        struct((s,), jnp.int32), struct((s,), jnp.float32),
+        struct((s, EMBED), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text) == [("f32", (EMBED, n))]
+    assert not _table_copies(text, n)
+    mem = compiled.memory_analysis()
+    record_property(f"fm_kernel_update_temp_bytes_{n}",
+                    int(mem.temp_size_in_bytes))
+    print(f"FM kernel update {n} x {EMBED}: temp "
+          f"{mem.temp_size_in_bytes} B")
 
 
 @pytest.fixture(scope="module")
