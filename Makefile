@@ -10,7 +10,7 @@
 #                     single-chip + 8-device-mesh dryrun
 #   make test         pytest only
 #   make native       optimized native core only
-#   make bench        the driver benchmark (real device)
+#   make bench        the benchmark's main cell, criteo_fm.seq (TPU only)
 
 PY ?= python
 LIBASAN := $(shell gcc -print-file-name=libasan.so)
@@ -24,7 +24,7 @@ check: lint native test sanitizers dryrun
 	@echo "== make check: ALL GATES PASSED =="
 
 lint:
-	$(PY) -m compileall -q dmlc_core_tpu tests benchmarks bench.py __graft_entry__.py
+	$(PY) -m compileall -q dmlc_core_tpu tests benchmarks __graft_entry__.py
 	$(PY) tools/lint.py
 
 native:
@@ -46,7 +46,7 @@ dryrun: native
 	$(PY) __graft_entry__.py
 
 bench: native
-	$(PY) bench.py
+	$(PY) benchmarks/chip/run.py --workload criteo_fm.seq --seed 1 --seconds 20 --trace 0
 
 clean:
 	$(MAKE) -C native clean

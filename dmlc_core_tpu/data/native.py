@@ -244,8 +244,8 @@ def load(path: Optional[str] = None, force: bool = False) -> bool:
 
 def source_hash() -> str:
     """sha256 of the fastparse.cc the loaded .so was built from, or ''
-    (older builds). bench.py compares this against the on-disk source so a
-    failed rebuild can't silently benchmark a stale binary."""
+    (older builds). The chip benchmark compares this against the on-disk
+    source so a failed rebuild can't silently benchmark a stale binary."""
     if not AVAILABLE or not hasattr(_LIB, "dmlc_source_hash"):
         return ""
     return _LIB.dmlc_source_hash().decode()

@@ -286,7 +286,7 @@ def _recv_compressed(sock, meta: Dict, plen: int, crc: int, buf):
 
 def recv_alloc_bytes() -> int:
     """Process-wide fresh-allocation bytes on the payload receive path
-    — the bench/regression assertion surface: the delta over a drain
+    — the regression assertion surface: the delta over a drain
     stays 0 while every slot lands in a pooled recv buffer."""
     return int(_RECV_ALLOC.value())
 

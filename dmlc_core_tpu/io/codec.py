@@ -35,7 +35,7 @@ affinity/cgroup-aware usable-CPU count).
 
 Telemetry (docs/observability.md): ``io.codec.bytes_raw`` /
 ``io.codec.bytes_compressed`` counters (both directions — their ratio
-is the compression ratio bench.py reports), the
+is the compression ratio), the
 ``io.codec.decode_seconds`` histogram, and
 ``io.codec.cache_hits``/``cache_misses``.
 """

@@ -4,9 +4,9 @@
 ``FaultInjectingFileSystem`` wraps an inner backend and injects a
 *seeded, deterministic* fault schedule — short reads, mid-read
 ``ConnectionResetError``, N consecutive 5xx before an open succeeds,
-latency spikes, truncated writes — so tests, ``bench.py`` and
-``benchmarks/diag_starve.py`` can all prove the retry layer heals real
-failure shapes (a clean read and a chaos read must be byte-identical).
+latency spikes, truncated writes — so tests can prove the retry layer
+heals real failure shapes (a clean read and a chaos read must be
+byte-identical).
 
 URI grammar (both forms compose; the host form survives the split
 factory, which strips query args into dataset options):
@@ -103,8 +103,8 @@ class FaultSpec:
 def wrap_uri(uri: str, spec: str) -> str:
     """Prefix a plain local path / file:// URI with a fault:// host-form
     spec (``wrap_uri('/d/x.rec', 'resets=2,seed=7')`` →
-    ``fault://resets=2,seed=7/d/x.rec``) — the helper bench.py and
-    diag_starve use so a chaos run is one flag/env away."""
+    ``fault://resets=2,seed=7/d/x.rec``), so a chaos run is one flag/env
+    away."""
     if not spec:
         return uri
     path = uri[len("file://"):] if uri.startswith("file://") else uri

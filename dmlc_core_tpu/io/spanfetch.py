@@ -18,8 +18,8 @@ ranged reads:
 - **cgroup-aware default concurrency** (``DMLC_FETCH_THREADS``; default
   ``min(16, 2 × available_cpus())`` via utils/cpus.py — fetch threads
   park on the network, so they oversubscribe cores 2× but still respect
-  a container quota). ``DMLC_FETCH_THREADS=1`` is the serial baseline
-  the ``rec_remote_latency`` bench config scores against;
+  a container quota). ``DMLC_FETCH_THREADS=1`` is the serial
+  baseline;
 - **adaptive concurrency**: an AIMD ramp — concurrency starts low,
   +1 per evaluation window while delivered bandwidth keeps improving,
   halved when it collapses (the link is saturated and extra streams

@@ -1397,8 +1397,7 @@ class IndexedRecordIOSplitter(RecordIOSplitter):
         """The concurrent ranged-read engine (io/spanfetch.py) for
         REMOTE files, or None: local files keep the zero-copy
         mmap/pread ``_SpanReader`` fast path untouched, and
-        ``DMLC_FETCH_THREADS=1`` pins the serial baseline the
-        ``rec_remote_latency`` bench config scores against."""
+        ``DMLC_FETCH_THREADS=1`` pins the serial baseline."""
         if self._files_all_local() or _spanfetch.fetch_threads() <= 1:
             return None
         if self._span_fetcher is None:

@@ -4,8 +4,8 @@ The reference has no models (SURVEY header: "not a tensor/training
 framework"); its consumers are XGBoost/MXNet-style learners fed by
 RowBlockIter. These jitted learners play that downstream role for the TPU
 build — small, pure-functional, and the flagship (sparse logistic
-regression, the classic rabit/ps-lite workload) is what __graft_entry__ and
-bench.py exercise.
+regression, the classic rabit/ps-lite workload) is what __graft_entry__
+exercises; the chip benchmark trains the factorization machine.
 """
 
 from .common import sgd_update

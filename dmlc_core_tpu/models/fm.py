@@ -8,15 +8,15 @@ axis over the mesh's 'model' axis (see parallel/ and __graft_entry__).
 
 ``sgd_step`` updates only the rows a batch touches when the loss has no
 l2 term (see its docstring); with l2 every row has a gradient and the
-step stays dense. On one TPU chip a Pallas kernel writes the new ``v`` in
-one streaming pass, adding every slot's row gradient on the way
-(``_add_rows``); elsewhere, and on a mesh, XLA scatters the rows, merging
-duplicate ids first when a batch has few distinct ids (``_update_rows``).
+step stays dense. ``w`` always takes XLA's scatter of every slot. ``v`` has
+two writers (``_update_rows``): on one TPU chip a Pallas kernel writes the
+new ``v`` in one streaming pass, adding every slot's row gradient on the
+way (``_add_rows``); on a mesh and on other devices XLA scatters every
+slot into it.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -42,93 +42,6 @@ def _count_update_path(path: str) -> None:
         "(sparse-kernel | sparse | sparse-replicated | dense)",
         labels={"path": path},
     ).inc()
-
-
-# The merge serves XLA's scatter only: the mesh path, and devices other
-# than a TPU (one TPU chip takes the kernel, ``_add_rows``). A scatter
-# row costs about the same whatever its width and whether or not its id
-# repeats (≈100 ns on a v5e), so a batch with few distinct ids merges
-# its duplicates and scatters one row per distinct id, in the smallest of
-# the static sizes 1/8, 2/8, .. MERGED_EIGHTHS/8 of its slots that holds
-# them (chosen on the device). Merging costs gathers of the row gradients
-# in id order and a scan over them; with more distinct ids it costs more
-# than the rows it saves (at 2/3 distinct, 6 ms a step more than the
-# 32 ms scatter it replaces on a v5e), and the slots are scattered as
-# they are.
-MERGED_EIGHTHS = 3
-
-
-def _count_dedup_branches(n: int) -> None:
-    """Per trace of the sparse update: the static scatter sizes compiled."""
-    default_registry().counter(
-        "models.fm.dedup_branches",
-        help="FM sgd_step: static scatter sizes compiled for the row update",
-    ).inc(n)
-
-
-def _running_sums(starts, xs):
-    """Inclusive running sums of each array of ``xs`` along axis 0 that
-    restart where ``starts`` is set (``starts[0]`` must be): the
-    doubling scan, log2(S) elementwise passes. Each sum is a balanced
-    tree of its own terms, so no total is a difference of two larger
-    sums (which would lose float32 digits to cancellation)."""
-    s = starts.shape[0]
-    d = 1
-    while d < s:
-        keep = starts
-        xs = [jnp.where(keep.reshape((s,) + (1,) * (x.ndim - 1)), x,
-                        x + jnp.pad(x[:-d], [(d, 0)] + [(0, 0)] * (x.ndim - 1)))
-              for x in xs]
-        starts = keep | jnp.pad(starts[:-d], (d, 0), constant_values=True)
-        d *= 2
-    return xs
-
-
-def _scatter_merged(w, v, ids, gw, gv, sid, perm, differs, *, size, lr):
-    """Sum the row gradients of equal ids and add −lr·(sum) into ``w``
-    and ``v``, one scatter row per distinct id, ``size`` rows in all.
-
-    The gradients are gathered in id order (``sid``, ``perm``: the ids
-    sorted with their slots) and each run of equal ids summed
-    (``_running_sums``); the run ends, which hold the totals, are moved
-    to the front. Rows past the distinct count get ids past the table's
-    end, distinct and ascending, and the scatter drops them.
-    """
-    s = ids.shape[0]
-    true = jnp.ones((1,), bool)
-    sw, sv = _running_sums(jnp.concatenate([true, differs]),
-                           [jnp.take(gw, perm), jnp.take(gv, perm, axis=0)])
-    slot = jax.lax.iota(jnp.int32, s)
-    ends = jnp.sort(jnp.where(jnp.concatenate([differs, true]), slot, s))[:size]
-    at = jnp.minimum(ends, s - 1)
-    uid = jnp.where(ends < s, jnp.take(sid, at),
-                    w.shape[0] + jax.lax.iota(jnp.int32, size))
-    flags = dict(mode="drop", indices_are_sorted=True, unique_indices=True)
-    return (w.at[uid].add(-lr * jnp.take(sw, at), **flags),
-            v.at[uid].add(-lr * jnp.take(sv, at, axis=0), **flags))
-
-
-def _scatter_slots(w, v, ids, gw, gv, *_sorted, lr):
-    """Add −lr·(row gradient) into ``w`` and ``v`` at every slot's id."""
-    return w.at[ids].add(-lr * gw), v.at[ids].add(-lr * gv)
-
-
-def _update_rows(w, v, ids, gw, gv, lr: float):
-    """``w``/``v`` with −lr·(row gradient) added at ``ids`` (duplicate
-    ids summed, as the dense gradient sums them), by the smallest scatter
-    that holds the batch's distinct ids."""
-    s = ids.shape[0]
-    sid, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, s)), num_keys=1)
-    differs = sid[1:] != sid[:-1]
-    n = 1 + jnp.sum(differs, dtype=jnp.int32)
-    sizes = sorted({-(-s * k // 8) for k in range(1, MERGED_EIGHTHS + 1)} - {s})
-    branches = [functools.partial(_scatter_merged, size=m, lr=lr)
-                for m in sizes] + [functools.partial(_scatter_slots, lr=lr)]
-    _count_dedup_branches(len(branches))
-    return jax.lax.switch(
-        jnp.searchsorted(jnp.asarray(sizes, jnp.int32), n),
-        branches, w, v, ids, gw, gv, sid, perm, differs,
-    )
 
 
 # The kernel's table block: table rows (columns of the [E, N] view) that
@@ -265,10 +178,15 @@ def _add_rows(table_t, sid, perm, rows_t):
       table_t)
 
 
-def _update_rows_kernel(w, v, ids, gw, gv, lr: float):
-    """``_update_rows`` on one TPU chip: XLA scatters every slot into
-    ``w`` (a scalar row costs 9–13 ns); the kernel writes the new ``v``
-    through the table's free [E, N] view."""
+def _update_rows(w, v, ids, gw, gv, lr: float, kernel: bool):
+    """``w`` and ``v`` with −lr·(row gradient) added at every slot's id
+    (equal ids sum, as the dense gradient sums them). XLA scatters every
+    slot into ``w`` (a scalar row costs 9–13 ns on a v5e). With
+    ``kernel`` (one TPU chip) the kernel writes the new ``v`` through the
+    table's free [E, N] view, over the ids sorted with their slots;
+    otherwise XLA scatters every slot into ``v`` too."""
+    if not kernel:
+        return w.at[ids].add(-lr * gw), v.at[ids].add(-lr * gv)
     s = ids.shape[0]
     sid, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, s)), num_keys=1)
     return (w.at[ids].add(-lr * gw),
@@ -374,11 +292,10 @@ class FactorizationMachine:
         so the step differentiates with respect to the gathered rows and
         adds −lr·(row gradient) into ``w`` and ``v`` at the batch's ids
         (duplicate ids sum, as the dense gradient sums them): no
-        table-sized gradient is built, zeroed or subtracted. On one TPU
-        chip a kernel writes the new ``v`` with the rows added
-        (``_add_rows``); elsewhere XLA scatters them, and a batch with
-        few distinct ids sums them first and scatters one row per id
-        (``_update_rows``). Traced
+        table-sized gradient is built, zeroed or subtracted. XLA
+        scatters every slot into ``w``; ``v`` is written by a kernel on
+        one TPU chip (``_add_rows``) and by XLA's scatter of every slot
+        elsewhere (``_update_rows``). Traced
         under a mesh (``parallel.data_parallel_step`` sets it), the ids
         and row gradients are first replicated over the mesh, so the
         partitioner all-gathers the rows instead of all-reducing a
@@ -405,15 +322,14 @@ class FactorizationMachine:
         ids = idx.reshape(-1)
         gw = g["w"].reshape(-1)
         gv = g["v"].reshape(-1, self.embed_dim)
-        update = (_update_rows_kernel if path == "sparse-kernel"
-                  else _update_rows)
         if path == "sparse-replicated":
             ids, gw, gv = jax.lax.with_sharding_constraint(
                 (ids, gw, gv), jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec()
                 ),
             )
-        w, v = update(params["w"], params["v"], ids, gw, gv, lr)
+        w, v = _update_rows(params["w"], params["v"], ids, gw, gv, lr,
+                            kernel=path == "sparse-kernel")
         new = {"w": w, "v": v, "b": params["b"] - lr * g["b"]}
         if not mesh.empty:
             new, loss_val = _done_on_every_device(new, loss_val, mesh)

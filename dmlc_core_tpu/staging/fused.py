@@ -1292,7 +1292,7 @@ class _GenericBatchStream:
     def io_stats(self):
         """Seek/span counters from the parser's source split (indexed
         shuffled reads), or an empty dict — same hook as the fused
-        producers, so the bench sees the I/O shape whichever path
+        producers, so a caller sees the I/O shape whichever path
         served the rows."""
         parser = getattr(self._parser, "_base", self._parser)
         source = getattr(

@@ -608,7 +608,7 @@ class MetricRegistry:
 
     def peak_gauge_values(self, prefix: str = "") -> Dict[str, float]:
         """Current values of every ``set_max``-style gauge (the
-        per-config peaks the bench report records)."""
+        per-run peaks a report records)."""
         return {k: g.value() for k, g in self._peak_gauges(prefix)}
 
     def reset_peak_gauges(self, prefix: str = "") -> int:

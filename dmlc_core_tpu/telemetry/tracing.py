@@ -26,8 +26,7 @@ as in tf.data-service and Ray's per-process event logs):
   one timeline; colliding pids are remapped, process labels kept.
 - **stall attribution** — ``stall_report()`` computes per-stage
   busy/stall seconds, ring-starvation gaps (wait spans longer than a
-  threshold) and a critical-path estimate — the analytical backend the
-  ``diag_starve``/``diag_infeed`` scalpels approximated by hand.
+  threshold) and a critical-path estimate.
 
 Dump-on-demand: SIGUSR2 (``install_signal_dump``, auto-installed on
 first use from the main thread; ``tools trace dump <pid>`` sends it)
@@ -37,8 +36,8 @@ stopping the process, and an atexit hook dumps automatically when
 leaves a trace file behind for ``tools trace merge``.
 
 Env knobs: ``DMLC_TRACE`` (``off``/``0``/``false`` disables; default
-ON — the recorder's cost is bounded by the bench invariant at <=3% of
-rec throughput), ``DMLC_TRACE_BUF_KB`` (per-thread ring budget,
+ON — a span costs two clock reads and a ring append),
+``DMLC_TRACE_BUF_KB`` (per-thread ring budget,
 default 256 — about 4k events), ``DMLC_TRACE_DIR`` (dump directory +
 the atexit-dump switch).
 
@@ -134,8 +133,8 @@ def enabled() -> bool:
 
 def set_enabled(on: Optional[bool]) -> None:
     """Force the recorder on/off for this process (None restores the
-    ``DMLC_TRACE`` env default). Used by tests and the bench overhead
-    probe; production uses the env knob."""
+    ``DMLC_TRACE`` env default). Used by tests; production uses the
+    env knob."""
     global _ENABLED_OVERRIDE, _ENABLED_ENV
     _ENABLED_OVERRIDE = on
     _ENABLED_ENV = None  # re-read the env when the override lifts
@@ -539,7 +538,7 @@ def stats() -> Dict[str, Any]:
         },
         # exact recorded-event total (resident + overwritten), summed
         # over RINGS — the per-name dict above folds threads sharing a
-        # pool name, this does not (the bench overhead probe deltas it)
+        # pool name, this does not
         "total_events": sum(r.n + r.dropped for r in rings),
         "dropped_rings": _DROPPED_RINGS,
     }
@@ -891,7 +890,7 @@ def stall_report(trace: dict, gap_ms: float = 10.0) -> dict:
       extent, attributed to the busiest thread's per-stage totals with
       the remainder explicit as ``unattributed_seconds``. An estimate
       (threads overlap; spans under-cover uninstrumented code), not a
-      proof — the honest version of what ``diag_infeed`` eyeballs.
+      proof.
     """
     by_thread: Dict[Tuple[int, int], List[dict]] = {}
     thread_names: Dict[Tuple[int, int], str] = {}

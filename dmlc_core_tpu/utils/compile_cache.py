@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``bench.py``, the examples) call
+Entry points (the chip benchmark's harness, the examples) call
 :func:`place_compile_cache` once at start; library code and tests never
 do. The path is part of the cache key, so it is fixed: a directory built
 from a tempdir, a pid or the time would never hit.

@@ -1591,7 +1591,7 @@ DMLC_API void dmlc_shuffle_mt19937(const uint32_t* state, int32_t mti,
 }
 
 // Build stamp: the Makefile passes -DDMLC_SRC_HASH="sha256 of fastparse.cc"
-// so callers (bench.py ensure_native) can detect a stale prebuilt .so after
+// so callers (the chip benchmark) can detect a stale prebuilt .so after
 // a failed rebuild instead of silently benchmarking last round's binary.
 DMLC_API const char* dmlc_source_hash() {
 #ifdef DMLC_SRC_HASH

@@ -25,7 +25,7 @@ def _refresh_native() -> None:
     parity suites (and every fused-kernel test) silently validate last
     round's binary. No toolchain → skip the rebuild with a visible
     reason on stderr; the source-hash stamp still flags the stale .so
-    wherever it matters (bench.ensure_native refuses it outright)."""
+    wherever it matters (the chip benchmark refuses it outright)."""
     import shutil
     import subprocess
 
@@ -124,7 +124,8 @@ def pytest_collection_modifyitems(config, items):
 # Tests never take the chip: a chip belongs to one process, and the
 # driver runs the suite in several workers. The env default above pins
 # the backend; the config update also covers a caller whose env names
-# another platform. The chip is reached only through chip_smoke.py.
+# another platform. The chip is reached only through the benchmark,
+# benchmarks/chip/run.py.
 try:
     import jax
 

@@ -312,8 +312,8 @@ def test_fault_injected_reads_heal_byte_identical(shuffle, tmp_path):
 
 def test_latency_spike_schedule_decodes_identically(tmp_path):
     """The fault-free latency-spike schedule (pure delay, no error):
-    the codec path must return identical bytes — the bench acceptance
-    shape (codec wins when the link, not the CPU, is the bottleneck)."""
+    the codec path must return identical bytes (the codec wins when the
+    link, not the CPU, is the bottleneck)."""
     from dmlc_core_tpu.io.faults import wrap_uri
 
     rec, _ = _write(tmp_path, "zlib")

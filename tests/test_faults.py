@@ -390,8 +390,7 @@ def test_chaos_parallel_fetch_byte_identical(mode, codec, tmp_path):
 
 def test_chaos_parallel_equals_serial_baseline(tmp_path, monkeypatch):
     """The DMLC_FETCH_THREADS=1 serial baseline and the concurrent
-    fetch produce identical bytes UNDER THE SAME chaos spec — the bench
-    invariant's correctness half, tier-1-fast."""
+    fetch produce identical bytes UNDER THE SAME chaos spec."""
     from tests.test_split_gather import (
         drain_records,
         make_indexed_rec,

@@ -176,24 +176,26 @@ def _zipf_fields(rng, shape):
     return out
 
 
-# batch shape 16 x 20 (fm_batches): 320 slots, merged scatter sizes in
-# steps of 40 up to 120; above 120 distinct ids the slots are scattered
+# batch shape 16 x 20 (fm_batches): 320 slots. The sparse paths scatter
+# every slot, and duplicate ids must sum as the dense formula sums them:
+# one id in every slot, a few hot ids, Zipf fields, and 80, 81, 120 and
+# 121 distinct ids over the 320 slots
 FM_IDS = {
     "criteo": None,
     # one row takes all 320 slots' gradients: at lr 0.5 its three steps
     # are large enough that float32 rounding of the sums, in any order,
     # grows past 1e-6 (against float64, after three steps: the dense
-    # formula 8.8e-7, the merged sums 2.7e-6, one scatter row per slot
-    # 1.9e-5), so this case steps at a tenth of that
+    # formula 8.8e-7, one scatter row per slot 1.9e-5), so this case
+    # steps at a tenth of that
     "one_id": lambda rng, shape: np.full(shape, 5),
     "three_ids": lambda rng, shape: np.where(
         rng.uniform(size=shape) < 0.5, rng.choice([7, 40, 99], size=shape),
         rng.integers(0, 120, size=shape)),
     "zipf": _zipf_fields,
-    "bucket_edge": _distinct(80),
-    "over_bucket_edge": _distinct(81),
-    "largest_merge": _distinct(120),
-    "over_largest_merge": _distinct(121),
+    "distinct_80": _distinct(80),
+    "distinct_81": _distinct(81),
+    "distinct_120": _distinct(120),
+    "distinct_121": _distinct(121),
 }
 
 
@@ -204,16 +206,15 @@ FM_IDS = {
     (0.0, None, "sparse", "one_id"),
     (0.0, None, "sparse", "three_ids"),
     (0.0, None, "sparse", "zipf"),
-    (0.0, None, "sparse", "bucket_edge"),
-    (0.0, None, "sparse", "over_bucket_edge"),
-    (0.0, None, "sparse", "largest_merge"),
-    (0.0, None, "sparse", "over_largest_merge"),
+    (0.0, None, "sparse", "distinct_80"),
+    (0.0, None, "sparse", "distinct_81"),
+    (0.0, None, "sparse", "distinct_120"),
+    (0.0, None, "sparse", "distinct_121"),
     (0.0, (2, 2), "sparse-replicated", "zipf"),
 ], ids=["sparse", "l2_dense", "mesh_2x2", "one_id", "three_ids", "zipf",
-        "bucket_edge", "over_bucket_edge", "largest_merge",
-        "over_largest_merge", "mesh_2x2_zipf"])
+        "distinct_80", "distinct_81", "distinct_120", "distinct_121",
+        "mesh_2x2_zipf"])
 def test_fm_sgd_step_update_paths(l2, mesh_shape, path, ids):
-    from dmlc_core_tpu.models import fm
     from dmlc_core_tpu.telemetry import default_registry
 
     lr = 0.05 if ids == "one_id" else 0.5
@@ -249,11 +250,7 @@ def test_fm_sgd_step_update_paths(l2, mesh_shape, path, ids):
     for b in batches:
         got, _loss = step(got, b)
     ticks = {k: v for k, v in view.delta().items() if v}
-    want = {f'models.fm.update_path{{path="{path}"}}': 1.0}
-    if path != "dense":
-        # the merged sizes 40, 80, 120 and the slots as they are
-        want["models.fm.dedup_branches"] = float(fm.MERGED_EIGHTHS + 1)
-    assert ticks == want
+    assert ticks == {f'models.fm.update_path{{path="{path}"}}': 1.0}
     for k in ("w", "v", "b"):
         if path == "dense":  # the l2 step is exactly the dense formula
             np.testing.assert_array_equal(np.asarray(got[k]), ref[k])
@@ -322,7 +319,7 @@ def test_fm_kernel_update_matches_scatter(case):
     gw = rng.normal(size=s).astype(np.float32)
     gv = rng.normal(size=(s, e)).astype(np.float32)
     lr = 0.5
-    update = jax.jit(lambda *a: fm._update_rows_kernel(*a, lr=lr))
+    update = jax.jit(lambda *a: fm._update_rows(*a, lr=lr, kernel=True))
     with pltpu.force_tpu_interpret_mode():
         got_w, got_v = update(w, v, ids, gw, gv)
     for got, table, rows in ((got_w, w, gw), (got_v, v, gv)):

@@ -6,8 +6,8 @@ pre-change ``shuffle='record'`` loop for the same (seed, epoch) — on v1
 AND compressed containers, through the zero-copy ``next_gather_batch``
 handoff AND the framed-bytes fallback, via the fused native producer AND
 the generic batcher, with fault:// chaos healed by retries — and the
-gather path must actually BEAT the legacy per-record loop (the bench
-invariant, so the 13x shuffled-read wall can't silently regress).
+gather path must actually BEAT the legacy per-record loop (so the 13x
+shuffled-read wall can't silently regress).
 """
 
 import os

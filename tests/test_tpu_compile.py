@@ -8,9 +8,9 @@ at no chip time. The topology is described only inside the ``topo``
 fixture (never at import): only one process may load libtpu, and every
 xdist worker imports this file.
 
-Sizes are the chip smoke's (``chip_smoke.py``): Criteo layout K=39,
-batch 8192, f16 values, int32 indices (what ``BatchSpec`` stages), and an
-FM of 2^24 features x E=16.
+Sizes are the ``criteo_fm`` benchmark configuration's: Criteo layout
+K=39, batch 8192, f16 values, int32 indices (what ``BatchSpec`` stages),
+and an FM of 2^24 features x E=16.
 """
 
 import re
@@ -183,7 +183,7 @@ def test_fm_kernel_update_compiles(one_chip, n, record_property):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     s = B * K
-    update = jax.jit(lambda *a: fm._update_rows_kernel(*a, lr=0.1))
+    update = jax.jit(lambda *a: fm._update_rows(*a, lr=0.1, kernel=True))
     compiled = update.lower(
         struct((n,), jnp.float32), struct((n, EMBED), jnp.float32),
         struct((s,), jnp.int32), struct((s,), jnp.float32),
@@ -262,25 +262,29 @@ def test_spmd_fm_step_all_reduces_no_table(fm_2x2):
     assert _ops(text, "all-gather")
 
 
-def _computation(text, name):
-    """The body of the HLO computation ``name`` in ``text``."""
-    lines = text.splitlines()
-    start = next(i for i, ln in enumerate(lines)
-                 if ln.startswith((f"%{name} ", f"{name} ")))
-    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
-    return "\n".join(lines[start:end])
+def _sorts(text):
+    """(dtype, dims) of every sort's results, a list for each sort."""
+    return [
+        [(dt, tuple(int(x) for x in dims.split(",") if x))
+         for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                    ln.split(" sort(", 1)[0])]
+        for ln in text.splitlines() if " sort(" in ln
+    ]
 
 
-def _updates(text, line):
-    """Whether an entry line scatters into the tables: a scatter itself,
-    or a conditional (the scatter size chosen on the device) with a
-    scatter in its branches."""
-    if "scatter-add" in line:
-        return True
-    m = re.search(r"branch_computations=\{([^}]*)\}", line)
-    return bool(m) and all(
-        "scatter-add" in _computation(text, b.strip().lstrip("%"))
-        for b in m.group(1).split(","))
+def test_spmd_fm_step_scatters_every_slot(fm_2x2):
+    # the mesh scatters every slot into both tables: no conditional and no
+    # sort of the flat ids with their slots (XLA's own scatter of the
+    # scalar w sorts its (id, update) pairs, s32 with f32)
+    text = fm_2x2.as_text()
+    assert " conditional(" not in text
+    sorts = _sorts(text)
+    assert [("s32", (B * K,))] * 2 not in sorts, sorts
+
+
+def _updates(line):
+    """Whether an entry line scatters into the tables."""
+    return "scatter-add" in line
 
 
 def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
@@ -289,7 +293,7 @@ def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
     # device's update
     text = fm_2x2.as_text()
     entry = text.split("\nENTRY ", 1)[1].splitlines()
-    scatters = [i for i, ln in enumerate(entry) if _updates(text, ln)]
+    scatters = [i for i, ln in enumerate(entry) if _updates(ln)]
     whole = [i for i, ln in enumerate(entry)
              if " all-reduce(" in ln and "replica_groups={{0,1,2,3}}" in ln]
     assert scatters and whole and whole[-1] > scatters[-1]

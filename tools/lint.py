@@ -200,8 +200,6 @@ DEFAULT_PATHS = [
     "benchmarks",
     "tools",
     "examples",
-    "bench.py",
-    "chip_smoke.py",
     "__graft_entry__.py",
 ]
 
