@@ -9,10 +9,11 @@ axis over the mesh's 'model' axis (see parallel/ and __graft_entry__).
 ``sgd_step`` updates only the rows a batch touches when the loss has no
 l2 term (see its docstring); with l2 every row has a gradient and the
 step stays dense. ``w`` always takes XLA's scatter of every slot. ``v`` has
-two writers (``_update_rows``): on one TPU chip a Pallas kernel writes the
-new ``v`` in one streaming pass, adding every slot's row gradient on the
-way (``_add_rows``); on a mesh and on other devices XLA scatters every
-slot into it.
+two writers (``_update_rows``): on a TPU a Pallas kernel writes the new
+``v`` in one streaming pass, adding every slot's row gradient on the way
+(``_add_rows``): the whole table on one chip, and on a mesh each device's
+``P(None, 'model')`` shard under ``shard_map``. On other devices XLA
+scatters every slot into it.
 """
 
 from __future__ import annotations
@@ -39,14 +40,19 @@ def _count_update_path(path: str) -> None:
     default_registry().counter(
         "models.fm.update_path",
         help="FM sgd_step traces by update path "
-        "(sparse-kernel | sparse | sparse-replicated | dense)",
+        "(sparse-kernel | sparse-kernel-sharded | sparse | "
+        "sparse-replicated | dense)",
         labels={"path": path},
     ).inc()
 
 
 # The kernel's table block: table rows (columns of the [E, N] view) that
-# one grid step copies to its output and updates (PERF.md §6).
+# one grid step copies to its output and updates, at E = 16 (PERF.md §6).
 KERNEL_COLS = 8192
+# Below that E a block keeps the same bytes (512 KiB): a grid step costs
+# ≈0.44 µs on a v5e whatever its width, so at E = 8 (the 2x2 mesh's v
+# shard) 16384 rows a block save ≈1.8 of ≈12.4 ms a step (PERF.md §6).
+_BLOCK_E = 16
 _LANES = 128
 # Sorted ids and their slots come into SMEM (1 MiB) this many at a time.
 _ID_CHUNK = 1024
@@ -54,29 +60,42 @@ _ID_CHUNK = 1024
 _UNROLL = 4
 # The [E, S] row gradients stay whole in VMEM (128 MiB on a v5e).
 _ROWS_VMEM_BYTES = 32 << 20
+# The mesh axis that splits v's E axis: v is placed on P(None, _V_AXIS).
+_V_AXIS = "model"
+
+
+def _kernel_fits(platform: str, embed_dim: int, slots: int) -> bool:
+    """A TPU, a table that XLA lays out E-minor-major (E < 128, so its
+    [E, N] view is a bitcast) and [E, S] row gradients that fit VMEM."""
+    padded = -(-slots // _LANES) * _LANES
+    return (platform == "tpu" and embed_dim < _LANES
+            and padded * embed_dim * 4 <= _ROWS_VMEM_BYTES)
 
 
 def _update_path(platform: str, mesh, l2: float, embed_dim: int,
                  slots: int) -> str:
-    """Which update ``sgd_step`` compiles to. The kernel needs a TPU with
-    no mesh, no l2, a table that XLA lays out E-minor-major (E < 128, so
-    its [E, N] view is a bitcast) and row gradients that fit VMEM."""
+    """Which update ``sgd_step`` compiles to. The kernel needs no l2 and
+    ``_kernel_fits`` for the table one device writes: the whole table
+    with no mesh, its E / |model| shard on a mesh with a ``model`` axis
+    (``v`` placed on ``P(None, 'model')``)."""
     if l2:
         return "dense"
-    if not mesh.empty:
-        return "sparse-replicated"
-    padded = -(-slots // _LANES) * _LANES
-    if (platform == "tpu" and embed_dim < _LANES
-            and padded * embed_dim * 4 <= _ROWS_VMEM_BYTES):
-        return "sparse-kernel"
-    return "sparse"
+    if mesh.empty:
+        if _kernel_fits(platform, embed_dim, slots):
+            return "sparse-kernel"
+        return "sparse"
+    parts = mesh.shape.get(_V_AXIS)
+    if (parts and embed_dim % parts == 0
+            and _kernel_fits(platform, embed_dim // parts, slots)):
+        return "sparse-kernel-sharded"
+    return "sparse-replicated"
 
 
 def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
                      out_ref, sid_buf, perm_buf, loaded, sem):
-    """One block of ``KERNEL_COLS`` table rows: copy it, then add the row
-    of every slot whose sorted id falls in it (``starts`` bounds the
-    block's sorted slots), one lane of a 128-row tile at a time."""
+    """One block of table rows: copy it, then add the row of every slot
+    whose sorted id falls in it (``starts`` bounds the block's sorted
+    slots), one lane of a 128-row tile at a time."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -85,7 +104,7 @@ def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
 
     out_ref[...] = table_ref[...]
     lo, hi = starts_ref[i], starts_ref[i + 1]
-    base = i * KERNEL_COLS
+    base = i * out_ref.shape[1]
     lane_of = jax.lax.broadcasted_iota(
         jnp.int32, (out_ref.shape[0], _LANES), 1)
 
@@ -143,13 +162,14 @@ def _add_rows(table_t, sid, perm, rows_t):
     after another. Ids outside [0, N) are dropped."""
     e, n = table_t.shape
     s = sid.shape[0]
+    cols = KERNEL_COLS * max(1, _BLOCK_E // e)
     rows_t = jnp.pad(rows_t, ((0, 0), (0, -(-s // _LANES) * _LANES - s)))
     pad = -(-s // _ID_CHUNK) * _ID_CHUNK - s
-    blocks = pl.cdiv(n, KERNEL_COLS)
+    blocks = pl.cdiv(n, cols)
     starts = jnp.searchsorted(
-        sid, KERNEL_COLS * jax.lax.iota(jnp.int32, blocks + 1)
+        sid, cols * jax.lax.iota(jnp.int32, blocks + 1)
     ).astype(jnp.int32)
-    block = pl.BlockSpec((e, KERNEL_COLS), lambda i, _starts: (0, i))
+    block = pl.BlockSpec((e, cols), lambda i, _starts: (0, i))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         _add_rows_kernel,
@@ -170,7 +190,7 @@ def _add_rows(table_t, sid, perm, rows_t):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # the gradients, the double-buffered in and out blocks, slack
-            vmem_limit_bytes=rows_t.size * 4 + 16 * e * KERNEL_COLS
+            vmem_limit_bytes=rows_t.size * 4 + 16 * e * cols
             + (4 << 20),
         ),
         name="fm_add_rows",
@@ -178,25 +198,42 @@ def _add_rows(table_t, sid, perm, rows_t):
       table_t)
 
 
-def _update_rows(w, v, ids, gw, gv, lr: float, kernel: bool):
+def _update_rows(w, v, ids, gw, gv, lr: float, kernel: bool, mesh=None):
     """``w`` and ``v`` with −lr·(row gradient) added at every slot's id
     (equal ids sum, as the dense gradient sums them). XLA scatters every
     slot into ``w`` (a scalar row costs 9–13 ns on a v5e). With
-    ``kernel`` (one TPU chip) the kernel writes the new ``v`` through the
-    table's free [E, N] view, over the ids sorted with their slots;
-    otherwise XLA scatters every slot into ``v`` too."""
+    ``kernel`` the kernel writes the new ``v`` through the table's free
+    [E, N] view, over the ids sorted with their slots: on one TPU chip
+    the whole table; with a non-empty ``mesh`` each device its own
+    ``P(None, 'model')`` shard under ``shard_map``, from the ids and row
+    gradients the caller replicated over the mesh, so no collective
+    moves them (the trace cannot see ``v``'s placement over the mesh's
+    automatic axes: ``v`` placed otherwise is resharded by the
+    partitioner, and stays correct). Otherwise XLA scatters every slot
+    into ``v`` too."""
     if not kernel:
         return w.at[ids].add(-lr * gw), v.at[ids].add(-lr * gv)
     s = ids.shape[0]
     sid, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, s)), num_keys=1)
-    return (w.at[ids].add(-lr * gw),
-            _add_rows(v.T, sid, perm, (-lr * gv).T).T)
+
+    def add(v, sid, perm, gv):
+        return _add_rows(v.T, sid, perm, (-lr * gv).T).T
+
+    if mesh is not None and not mesh.empty:
+        from jax.sharding import PartitionSpec as P
+
+        shard = P(None, _V_AXIS)
+        # the kernel's output type carries no mesh axes to check; each
+        # shard is the same on every device of the data axis
+        add = jax.shard_map(add, mesh=mesh, in_specs=(shard, P(), P(), shard),
+                            out_specs=shard, check_vma=False)
+    return w.at[ids].add(-lr * gw), add(v, sid, perm, gv)
 
 
 def _done_on_every_device(params: Params, loss: jax.Array, mesh):
     """Return ``loss`` only once every device of ``mesh`` holds ``params``.
 
-    After the all-gather no collective follows the scatter, so the
+    After the all-gather no collective follows the update, so the
     devices finish the step at different times, while a caller waiting
     on the replicated loss reads it from one device. A caller that runs
     ahead (two steps in flight, no donation) then allocates the next
@@ -294,7 +331,8 @@ class FactorizationMachine:
         (duplicate ids sum, as the dense gradient sums them): no
         table-sized gradient is built, zeroed or subtracted. XLA
         scatters every slot into ``w``; ``v`` is written by a kernel on
-        one TPU chip (``_add_rows``) and by XLA's scatter of every slot
+        a TPU (``_add_rows``: on a mesh each device writes its
+        ``P(None, 'model')`` shard) and by XLA's scatter of every slot
         elsewhere (``_update_rows``). Traced
         under a mesh (``parallel.data_parallel_step`` sets it), the ids
         and row gradients are first replicated over the mesh, so the
@@ -322,14 +360,15 @@ class FactorizationMachine:
         ids = idx.reshape(-1)
         gw = g["w"].reshape(-1)
         gv = g["v"].reshape(-1, self.embed_dim)
-        if path == "sparse-replicated":
+        if not mesh.empty:
             ids, gw, gv = jax.lax.with_sharding_constraint(
                 (ids, gw, gv), jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec()
                 ),
             )
         w, v = _update_rows(params["w"], params["v"], ids, gw, gv, lr,
-                            kernel=path == "sparse-kernel")
+                            kernel=path.startswith("sparse-kernel"),
+                            mesh=mesh)
         new = {"w": w, "v": v, "b": params["b"] - lr * g["b"]}
         if not mesh.empty:
             new, loss_val = _done_on_every_device(new, loss_val, mesh)

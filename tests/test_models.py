@@ -299,6 +299,9 @@ KERNEL_CASES = {
     "empty_block": (3 * KERNEL_COLS, 16, 300, _skip_block),
     "table_ends": (KERNEL_COLS + 77, 16, 300, _table_ends),
     "embed_8": (KERNEL_COLS + 77, 8, 300, _table_ends),
+    # E 8 (a 2x2 v shard): blocks of 2 * KERNEL_COLS rows, the ids in
+    # several SMEM chunks
+    "embed_8_blocks": (5 * KERNEL_COLS + 77, 8, 2600, _table_ends),
     # more slots than one SMEM chunk of ids: blocks that span chunks
     "id_chunks": (3 * KERNEL_COLS + 77, 16, 2600,
                   lambda rng, n, s: rng.integers(0, n, size=s)),
@@ -334,20 +337,26 @@ def test_fm_kernel_update_matches_scatter(case):
     ("tpu", None, 0.0, 16, 8192 * 39, "sparse-kernel"),
     ("tpu", None, 0.0, 8, 300, "sparse-kernel"),
     ("cpu", None, 0.0, 16, 8192 * 39, "sparse"),
-    ("tpu", (2, 2), 0.0, 16, 8192 * 39, "sparse-replicated"),
+    ("tpu", (2, 2), 0.0, 16, 8192 * 39, "sparse-kernel-sharded"),
     ("tpu", None, 1e-3, 16, 8192 * 39, "dense"),
     ("cpu", (2, 2), 1e-3, 16, 8192 * 39, "dense"),
     ("tpu", None, 0.0, 128, 8192 * 39, "sparse"),
     ("tpu", None, 0.0, 16, 1 << 20, "sparse"),
+    # the mesh's local [8, S] rows: 2^21 slots pass the VMEM budget
+    ("tpu", (2, 2), 0.0, 16, 1 << 21, "sparse-replicated"),
+    ("cpu", (2, 2), 0.0, 16, 8192 * 39, "sparse-replicated"),
+    ("tpu", (4,), 0.0, 16, 8192 * 39, "sparse-replicated"),
 ], ids=["one_tpu", "one_tpu_small", "cpu", "mesh", "l2", "cpu_mesh_l2",
-        "embed_128", "rows_past_vmem"])
+        "embed_128", "rows_past_vmem", "mesh_rows_past_vmem", "cpu_mesh",
+        "mesh_no_model_axis"])
 def test_fm_update_path_rule(platform, mesh_shape, l2, embed, slots, path):
     from dmlc_core_tpu.models import fm
 
     if mesh_shape is None:
         mesh = jax.sharding.get_abstract_mesh()
     else:
-        mesh = make_mesh(mesh_shape, ("data", "model"),
+        axes = ("data", "model")[:len(mesh_shape)]
+        mesh = make_mesh(mesh_shape, axes,
                          devices=jax.devices("cpu")[:4]).abstract_mesh
     assert fm._update_path(platform, mesh, l2, embed, slots) == path
 
@@ -383,6 +392,55 @@ def test_fm_sgd_step_kernel_path(monkeypatch):
     for k in ("w", "v", "b"):
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref[k]),
                                    rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("ids", ["criteo", "zipf"])
+def test_fm_sgd_step_sharded_kernel_path(monkeypatch, ids):
+    """The 2x2 data x model step on the sharded kernel path (steered to
+    it as on a TPU mesh: each device's v shard written by the kernel)
+    against the dense formula; zipf puts duplicate ids on every device."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dmlc_core_tpu.models import fm
+    from dmlc_core_tpu.telemetry import default_registry
+
+    rule = fm._update_path
+    monkeypatch.setattr(fm, "_update_path",
+                        lambda _platform, *a: rule("tpu", *a))
+    lr, d = 0.5, 200
+    # E 16 split over model: each device's shard is [d, 8], as on the chip
+    model = FactorizationMachine(num_features=d, embed_dim=16)
+    params = model.init(jax.random.PRNGKey(7))
+    rng = np.random.default_rng(8)
+    batches = fm_batches(rng, 3, d)
+    if FM_IDS[ids] is not None:
+        batches = _with_ids(rng, batches, FM_IDS[ids])
+    ref_step = _dense_sgd(model, lr)
+    ref = params
+    for b in batches:
+        ref = ref_step(ref, b)
+    view = default_registry().scoped("models.fm.")
+    mesh = make_mesh((2, 2), ("data", "model"),
+                     devices=jax.devices("cpu")[:4])
+    rules = {"v": P(None, "model")}
+    step = data_parallel_step(lambda p, b: model.sgd_step(p, b, lr=lr),
+                              mesh, donate_params=False, param_rules=rules)
+    got = shard_params(params, mesh, rules)
+    with pltpu.force_tpu_interpret_mode():
+        for b in batches:
+            got, _loss = step(got, b)
+    ticks = {k: v for k, v in view.delta().items() if v}
+    assert ticks == {'models.fm.update_path{path="sparse-kernel-sharded"}':
+                     1.0}
+    for k in ("w", "v", "b"):
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref[k]),
+                                   rtol=0, atol=1e-6)
+    untouched = np.setdiff1d(np.arange(d),
+                             np.concatenate([b["indices"] for b in batches]))
+    assert untouched.size
+    for k in ("w", "v"):
+        np.testing.assert_array_equal(np.asarray(got[k])[untouched],
+                                      np.asarray(params[k])[untouched])
 
 def test_dense_layout_forward_matches_ell():
     rng = np.random.default_rng(4)

@@ -109,9 +109,9 @@ def _ops(text, op):
     ]
 
 
-def _on_one_tpu(mp):
-    """Steer the FM step to the update it takes on one TPU chip: the
-    process's backend is the CPU here."""
+def _on_tpu(mp):
+    """Steer the FM step to the update it takes on a TPU (one chip or a
+    mesh of them): the process's backend is the CPU here."""
     from dmlc_core_tpu.models import fm
 
     rule = fm._update_path
@@ -125,7 +125,7 @@ def fm_one_chip(one_chip):
     batch = _criteo_batch_structs(lambda _shape: one_chip)
     step = jax.jit(lambda p, b: model.sgd_step(p, b, lr=0.1))
     with pytest.MonkeyPatch.context() as mp:
-        _on_one_tpu(mp)
+        _on_tpu(mp)
         return step.lower(params, batch).compile()
 
 
@@ -237,10 +237,15 @@ def fm_2x2(mesh_2x2):
     batch = _criteo_batch_structs(
         lambda shape: NamedSharding(mesh, P("data", *[None] * (len(shape) - 1)))
     )
+    # undonated, as the benchmark runs it: a donated v shard is the
+    # kernel's output buffer, so XLA copies the shard in before the kernel
     step = data_parallel_step(
         lambda p, b: model.sgd_step(p, b, lr=0.1), mesh, param_rules=rules,
+        donate_params=False,
     )
-    return step.jitted(params, batch).lower(params, batch).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        _on_tpu(mp)
+        return step.jitted(params, batch).lower(params, batch).compile()
 
 
 def test_spmd_fm_step_fits_four_chips(fm_2x2):
@@ -262,38 +267,40 @@ def test_spmd_fm_step_all_reduces_no_table(fm_2x2):
     assert _ops(text, "all-gather")
 
 
-def _sorts(text):
-    """(dtype, dims) of every sort's results, a list for each sort."""
-    return [
-        [(dt, tuple(int(x) for x in dims.split(",") if x))
-         for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
-                                    ln.split(" sort(", 1)[0])]
-        for ln in text.splitlines() if " sort(" in ln
-    ]
-
-
-def test_spmd_fm_step_scatters_every_slot(fm_2x2):
-    # the mesh scatters every slot into both tables: no conditional and no
-    # sort of the flat ids with their slots (XLA's own scatter of the
-    # scalar w sorts its (id, update) pairs, s32 with f32)
+def test_spmd_fm_step_writes_v_shard_in_the_kernel(fm_2x2):
+    # the 2x2 mesh: each device's kernel writes its new f32[N, 8] v shard
+    # (model splits E = 16) through the free [8, N] view; XLA copies no
+    # table or shard, runs no conditional, and moves nothing table-sized
+    # between devices
     text = fm_2x2.as_text()
+    local = EMBED // 2
+    assert _kernel_calls(text) == [("f32", (local, N_FEATURES))]
+    tables = {("f32", (N_FEATURES, e)) for e in (local, EMBED)}
+    tables |= {("f32", (e, N_FEATURES)) for e in (local, EMBED)}
+    copies = [r for op in ("copy", "transpose") for r in _ops(text, op)
+              if r in tables]
+    assert not copies, copies
     assert " conditional(" not in text
-    sorts = _sorts(text)
-    assert [("s32", (B * K,))] * 2 not in sorts, sorts
+    moved = [r for op in ("all-gather", "all-reduce", "all-to-all")
+             for r in _ops(text, op) if int(np.prod(r[1])) >= N_FEATURES]
+    assert not moved, moved
 
 
 def _updates(line):
-    """Whether an entry line scatters into the tables."""
-    return "scatter-add" in line
+    """Whether an entry line writes a table: XLA's scatter into ``w`` or
+    the kernel's call that writes the ``v`` shard."""
+    return "scatter-add" in line or "tpu_custom_call" in line
 
 
 def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
     # the loss comes out of an all-reduce over the whole mesh scheduled
-    # after the scatters, so a caller that waits on it waits for every
-    # device's update
+    # after the w scatter and the v kernel, so a caller that waits on it
+    # waits for every device's update
     text = fm_2x2.as_text()
     entry = text.split("\nENTRY ", 1)[1].splitlines()
-    scatters = [i for i, ln in enumerate(entry) if _updates(ln)]
+    updates = [i for i, ln in enumerate(entry) if _updates(ln)]
+    assert any("scatter-add" in entry[i] for i in updates)
+    assert any("tpu_custom_call" in entry[i] for i in updates)
     whole = [i for i, ln in enumerate(entry)
              if " all-reduce(" in ln and "replica_groups={{0,1,2,3}}" in ln]
-    assert scatters and whole and whole[-1] > scatters[-1]
+    assert whole and whole[-1] > updates[-1]
