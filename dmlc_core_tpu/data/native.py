@@ -211,8 +211,9 @@ def load(path: Optional[str] = None, force: bool = False) -> bool:
                     ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
                     ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
                     ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int32, ctypes.POINTER(_DenseResult)]
+                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                    ctypes.c_int64, ctypes.c_int32,
+                    ctypes.POINTER(_DenseResult)]
                 lib.dmlc_parse_libfm_ell.restype = None
                 HAS_LIBFM_ELL = True
             if hasattr(lib, "dmlc_parse_libsvm_ell"):
@@ -645,17 +646,26 @@ def parse_libfm_ell(
     weights: np.ndarray,
     row_start: int,
     cr_hint: int = -1,
+    fields: Optional[np.ndarray] = None,
 ) -> Optional[Tuple[int, int, int, int]]:
     """Fused libfm text parse → ELL batch rows (buffer contract of
     ``parse_rowrec_ell``, resumable-chunk contract of
     ``parse_libsvm_dense``). ``base`` is the resolved indexing base —
-    callers resolve libfm auto mode against the file head. Returns
+    callers resolve libfm auto mode against the file head. ``fields``
+    (int32 [capacity, K], or None to drop them) receives each kept
+    feature's field id at the feature's position. Returns
     (rows_written, bytes_consumed, truncated, has_cr), or None if the
     kernel is missing."""
     if not HAS_LIBFM_ELL:
         return None
+    from ..utils.logging import check
+
     mem = np.frombuffer(chunk, dtype=np.uint8)
     capacity, K = _check_ell_buffers(indices, values, nnz, labels, weights)
+    if fields is not None:
+        check(fields.flags.c_contiguous and fields.dtype == np.int32
+              and fields.shape == indices.shape,
+              "fields must be C-contiguous int32 shaped as indices")
     res = _DenseResult()
     _LIB.dmlc_parse_libfm_ell(
         ctypes.c_void_p(mem.ctypes.data + offset),
@@ -668,6 +678,7 @@ def parse_libfm_ell(
         ctypes.c_void_p(nnz.ctypes.data),
         ctypes.c_void_p(labels.ctypes.data),
         ctypes.c_void_p(weights.ctypes.data),
+        ctypes.c_void_p(None if fields is None else fields.ctypes.data),
         ctypes.c_int64(row_start),
         ctypes.c_int64(capacity),
         ctypes.c_int32(cr_hint),

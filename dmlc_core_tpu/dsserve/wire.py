@@ -367,5 +367,6 @@ def read_batch(meta: Dict, payload: np.ndarray) -> Batch:
         values=fields.get("values"),
         nnz=fields.get("nnz"),
         x=fields.get("x"),
+        fields=fields.get("fields"),
         packed=payload,
     )

@@ -9,6 +9,7 @@ exercises; the chip benchmark trains the factorization machine.
 """
 
 from .common import sgd_update
+from .ffm import FieldAwareFM
 from .fm import FactorizationMachine
 from .linear import LinearRegression, LogisticRegression
 
@@ -16,5 +17,6 @@ __all__ = [
     "LinearRegression",
     "LogisticRegression",
     "FactorizationMachine",
+    "FieldAwareFM",
     "sgd_update",
 ]

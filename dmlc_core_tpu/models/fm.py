@@ -91,6 +91,59 @@ def _update_path(platform: str, mesh, l2: float, embed_dim: int,
     return "sparse-replicated"
 
 
+# -- the sorted-slot machinery both row-update kernels share ------------------
+# (this one's FM add and ``ffm.py``'s AdaGrad): the ids sorted with their
+# slots, each table block's bounds in the sorted slots, and the sorted
+# ids (with what rides beside them) brought into the kernel in chunks.
+
+
+def _sort_slots(ids):
+    """(sorted ids, the slot each came from)."""
+    return jax.lax.sort((ids, jax.lax.iota(jnp.int32, ids.shape[0])),
+                        num_keys=1)
+
+
+def _block_starts(sid, cols: int, blocks: int):
+    """Where each block of ``cols`` table rows starts in the sorted ids,
+    and where the last one ends: ids < 0 fall before block 0 and ids
+    past the last block after it, so no block visits them."""
+    return jnp.searchsorted(
+        sid, cols * jax.lax.iota(jnp.int32, blocks + 1)
+    ).astype(jnp.int32)
+
+
+def _chunk_pad(n: int) -> int:
+    """Padding that makes ``n`` sorted slots whole chunks."""
+    return -(-n // _ID_CHUNK) * _ID_CHUNK - n
+
+
+def _visit_sorted_slots(lo, hi, loaded, copies, visit) -> None:
+    """Visit sorted slots [lo, hi) of a table block a chunk at a time.
+    ``copies(first)`` gives the async copies that bring the chunk from
+    sorted slot ``first`` in (skipped when it is in already: consecutive
+    blocks share a chunk; ``loaded[0]`` holds the chunk that is in, -1
+    before any); ``visit(a, b)`` gets the block's slots in the chunk as
+    offsets [a, b) into it."""
+
+    def chunk(c, _):
+        first = c * _ID_CHUNK
+
+        @pl.when(loaded[0] != c)
+        def _():
+            dmas = copies(first)
+            for d in dmas:
+                d.start()
+            for d in dmas:
+                d.wait()
+            loaded[0] = c
+
+        visit(jnp.maximum(lo, first) - first,
+              jnp.minimum(hi, first + _ID_CHUNK) - first)
+
+    last = jnp.where(hi > lo, (hi - 1) // _ID_CHUNK + 1, lo // _ID_CHUNK)
+    jax.lax.fori_loop(lo // _ID_CHUNK, last, chunk, None)
+
+
 def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
                      out_ref, sid_buf, perm_buf, loaded, sem):
     """One block of table rows: copy it, then add the row of every slot
@@ -126,23 +179,13 @@ def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
         for at, g in rows:
             out_ref[:, pl.ds(at, _LANES)] += g
 
-    def chunk(c, _):
-        first = c * _ID_CHUNK
+    def copies(first):
+        return (pltpu.make_async_copy(sid_hbm.at[pl.ds(first, _ID_CHUNK)],
+                                      sid_buf, sem.at[0]),
+                pltpu.make_async_copy(perm_hbm.at[pl.ds(first, _ID_CHUNK)],
+                                      perm_buf, sem.at[1]))
 
-        @pl.when(loaded[0] != c)  # consecutive blocks share a chunk
-        def _():
-            ids = pltpu.make_async_copy(
-                sid_hbm.at[pl.ds(first, _ID_CHUNK)], sid_buf, sem.at[0])
-            slots = pltpu.make_async_copy(
-                perm_hbm.at[pl.ds(first, _ID_CHUNK)], perm_buf, sem.at[1])
-            ids.start()
-            slots.start()
-            ids.wait()
-            slots.wait()
-            loaded[0] = c
-
-        a = jnp.maximum(lo, first) - first
-        b = jnp.minimum(hi, first + _ID_CHUNK) - first
+    def visit(a, b):
         groups = (b - a) // _UNROLL
         # a group's rows are loaded and rolled side by side before the
         # adds, which stay in order (two may hit one tile)
@@ -151,8 +194,7 @@ def _add_rows_kernel(starts_ref, sid_hbm, perm_hbm, rows_ref, table_ref,
         jax.lax.fori_loop(a + groups * _UNROLL, b,
                           lambda j, _: add([row(j)]), None)
 
-    last = jnp.where(hi > lo, (hi - 1) // _ID_CHUNK + 1, lo // _ID_CHUNK)
-    jax.lax.fori_loop(lo // _ID_CHUNK, last, chunk, None)
+    _visit_sorted_slots(lo, hi, loaded, copies, visit)
 
 
 def _add_rows(table_t, sid, perm, rows_t):
@@ -164,11 +206,9 @@ def _add_rows(table_t, sid, perm, rows_t):
     s = sid.shape[0]
     cols = KERNEL_COLS * max(1, _BLOCK_E // e)
     rows_t = jnp.pad(rows_t, ((0, 0), (0, -(-s // _LANES) * _LANES - s)))
-    pad = -(-s // _ID_CHUNK) * _ID_CHUNK - s
+    pad = _chunk_pad(s)
     blocks = pl.cdiv(n, cols)
-    starts = jnp.searchsorted(
-        sid, cols * jax.lax.iota(jnp.int32, blocks + 1)
-    ).astype(jnp.int32)
+    starts = _block_starts(sid, cols, blocks)
     block = pl.BlockSpec((e, cols), lambda i, _starts: (0, i))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
@@ -213,8 +253,7 @@ def _update_rows(w, v, ids, gw, gv, lr: float, kernel: bool, mesh=None):
     into ``v`` too."""
     if not kernel:
         return w.at[ids].add(-lr * gw), v.at[ids].add(-lr * gv)
-    s = ids.shape[0]
-    sid, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, s)), num_keys=1)
+    sid, perm = _sort_slots(ids)
 
     def add(v, sid, perm, gv):
         return _add_rows(v.T, sid, perm, (-lr * gv).T).T

@@ -7,7 +7,9 @@ stream of RowBlocks into fixed-shape numpy batches ready for device_put:
 - ``ell`` layout: capped-CSR / ELL — ``indices i32[B,K]``, ``values
   f32[B,K]`` with zero-padding and per-row ``nnz`` counts. ``K`` =
   max nnz per row; overflow policy 'truncate' (drop extra features,
-  counted in stats) or 'error'.
+  counted in stats) or 'error'. With ``BatchSpec.fields`` each slot's
+  field id (libfm's ``field:index:value``) rides beside it as
+  ``fields i32[B,K]`` (padding slots: field 0, value 0).
 - ``dense`` layout: scatter into ``x f32[B,D]`` — right for dense-ish data
   (HIGGS: 28 features) and the MXU, which wants large dense matmuls.
 
@@ -27,6 +29,7 @@ from ..data.row_block import RowBlock
 from ..utils.logging import Error, check
 
 __all__ = [
+    "FIELD_DTYPE",
     "Batch",
     "BatchSpec",
     "FixedShapeBatcher",
@@ -40,6 +43,8 @@ __all__ = [
 # views and the on-device bitcast unpack (pipeline.py) always see
 # aligned data, whole-batch or per-shard
 PACK_ALIGN = 8
+# staged field ids (``BatchSpec.fields``): int32, as the feature ids
+FIELD_DTYPE = np.dtype(np.int32)
 
 
 def _aligned(nbytes: int) -> int:
@@ -65,6 +70,22 @@ def alloc_packed_slot(sections):
     for (o, nb), (name, shape, dtype) in zip(offs, sections):
         views[name] = buf[o : o + nb].view(dtype).reshape(shape)
     return buf, views
+
+
+def ell_sections(spec: "BatchSpec"):
+    """The packed ELL slot's sections, in order: every ELL producer (the
+    fused kernels and ``FixedShapeBatcher``) stages this one layout."""
+    B, K = spec.batch_size, int(spec.max_nnz)  # type: ignore[arg-type]
+    sections = [
+        ("indices", (B, K), spec.index_dtype),
+        ("values", (B, K), spec.value_dtype),
+        ("nnz", (B,), np.int32),
+        ("labels", (B,), np.float32),
+        ("weights", (B,), np.float32),
+    ]
+    if spec.fields:
+        sections.append(("fields", (B, K), FIELD_DTYPE))
+    return sections
 
 
 def gather_slices(
@@ -126,7 +147,8 @@ class Batch:
     """One fixed-shape host batch. Arrays are numpy, ready for device_put.
 
     ``n_valid`` rows are real; rows beyond that are zero padding with
-    weight 0. For 'ell': indices/values are [B,K]; for 'dense': x is [B,D].
+    weight 0. For 'ell': indices/values are [B,K] (and fields, when the
+    spec stages them); for 'dense': x is [B,D].
     """
 
     labels: np.ndarray
@@ -136,6 +158,7 @@ class Batch:
     values: Optional[np.ndarray] = None
     nnz: Optional[np.ndarray] = None
     x: Optional[np.ndarray] = None
+    fields: Optional[np.ndarray] = None
     # single contiguous uint8 buffer the other arrays are views into
     # (fused producers set this): lets the staging pipeline issue ONE
     # device transfer per batch and bitcast-unpack in HBM, instead of
@@ -155,6 +178,8 @@ class Batch:
             out["indices"] = self.indices
             out["values"] = self.values
             out["nnz"] = self.nnz
+            if self.fields is not None:
+                out["fields"] = self.fields
         return out
 
 
@@ -169,6 +194,10 @@ class BatchSpec:
     num_features: D for 'dense' (required there); indices >= D follow
     ``overflow`` policy.
     overflow: 'truncate' | 'error'.
+    fields: 'ell' only — also stage each slot's field id as
+    ``FIELD_DTYPE`` [B, K] (libfm input; a field outside int32 after
+    the indexing base drops the slot as an id outside it does). Off,
+    every staged byte is what it is without the option.
     """
 
     batch_size: int
@@ -182,6 +211,7 @@ class BatchSpec:
     # upcast on device (standard TPU infeed trick; values like HIGGS's
     # N(0,1) features lose nothing that bf16 compute wouldn't lose anyway)
     value_dtype: np.dtype = np.dtype(np.float32)
+    fields: bool = False
 
     def __post_init__(self) -> None:
         check(self.layout in ("ell", "dense"), f"bad layout {self.layout!r}")
@@ -191,8 +221,16 @@ class BatchSpec:
             check(self.max_nnz is not None and self.max_nnz > 0,
                   "ell layout requires max_nnz")
         else:
+            check(not self.fields, "field ids ride the ell layout only")
             check(self.num_features is not None and self.num_features > 0,
                   "dense layout requires num_features")
+
+
+def _fits_field(field: np.ndarray) -> np.ndarray:
+    """Field ids that ``FIELD_DTYPE`` holds: in [0, 2^31) (a negative id
+    is a 1-based field 0, or junk)."""
+    f = np.asarray(field, dtype=np.int64)
+    return (f >= 0) & (f <= np.iinfo(FIELD_DTYPE).max)
 
 
 class FixedShapeBatcher:
@@ -234,16 +272,12 @@ class FixedShapeBatcher:
         # same slot layout as the fused ELL producers: the staging
         # pipeline stages generic-parser batches with the same single-DMA
         # (and packed-shard mesh) fast path the native kernels get
-        packed, v = alloc_packed_slot(
-            [
-                ("indices", (B, K), spec.index_dtype),
-                ("values", (B, K), spec.value_dtype),
-                ("nnz", (B,), np.int32),
-                ("labels", (B,), np.float32),
-                ("weights", (B,), np.float32),
-            ]
-        )
+        packed, v = alloc_packed_slot(ell_sections(spec))
         indices, values = v["indices"], v["values"]
+        fields = v.get("fields")
+        if fields is not None and blk.nnz:
+            check(blk.field is not None,
+                  "BatchSpec.fields needs a parser that keeps field ids")
         m = len(nnz_per_row)
         # fast path: uniform row width that fits K and the index dtype →
         # plain reshape+copy, no position scatter
@@ -255,10 +289,13 @@ class FixedShapeBatcher:
             and blk.index.size
             and int(blk.index.astype(np.uint64, copy=False).max())
             <= np.iinfo(spec.index_dtype).max
+            and (fields is None or _fits_field(blk.field).all())
         ):
             indices[:m, :k0] = blk.index.reshape(m, k0).astype(
                 spec.index_dtype, copy=False
             )
+            if fields is not None:
+                fields[:m, :k0] = blk.field.reshape(m, k0)
             vals = (
                 blk.value
                 if blk.value is not None
@@ -276,6 +313,9 @@ class FixedShapeBatcher:
             # feature via astype truncation
             idx64 = blk.index.astype(np.uint64, copy=False)
             fits = idx64 <= np.uint64(np.iinfo(spec.index_dtype).max)
+            if fields is not None:
+                # a field id that does not fit drops its slot as an id does
+                fits &= _fits_field(blk.field)
             n_unfit = int((keep & ~fits).sum())
             if n_unfit:
                 if spec.overflow == "error":
@@ -287,6 +327,8 @@ class FixedShapeBatcher:
                 keep &= fits
             r, p = row_ids[keep], pos[keep]
             indices[r, p] = idx64[keep].astype(spec.index_dtype)
+            if fields is not None:
+                fields[r, p] = blk.field[keep]
             vals = (
                 blk.value[keep]
                 if blk.value is not None
@@ -305,7 +347,8 @@ class FixedShapeBatcher:
         weights[:m] = 1.0 if blk.weight is None else blk.weight
         return Batch(
             labels=labels, weights=weights, n_valid=n_valid,
-            indices=indices, values=values, nnz=nnz, packed=packed,
+            indices=indices, values=values, nnz=nnz, fields=fields,
+            packed=packed,
         )
 
     def _to_dense(self, blk: RowBlock, n_valid: int) -> Batch:

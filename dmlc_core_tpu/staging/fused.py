@@ -24,8 +24,8 @@ whole batches.
   device layout, no CSR detour (the reference's premier text hot path,
   libsvm_parser.h:86-169).
 - FusedEllLibFMBatches: libfm text → ELL [B,K] (dmlc_parse_libfm_ell);
-  fields are validated then dropped (the ELL device layout carries no
-  field axis).
+  fields are validated, and staged as ``fields`` [B,K] beside the ids
+  when ``BatchSpec.fields`` asks for them (dropped otherwise).
 
 Producers expose ``ring_slots`` so consumers composing them with a
 prefetch/in-flight pipeline (StagingPipeline) can validate the ring is
@@ -55,7 +55,13 @@ from ..io.uri import URISpec, rejoin_query, uri_int
 from ..telemetry import default_registry as _default_registry
 from ..utils.logging import Error, check
 from ..utils.profiler import annotate
-from .batcher import Batch, BatchSpec, alloc_packed_slot, gather_slices
+from .batcher import (
+    Batch,
+    BatchSpec,
+    alloc_packed_slot,
+    ell_sections,
+    gather_slices,
+)
 
 # registry mirrors of the per-producer counters (the per-instance
 # attributes stay authoritative for io_stats(); these give the fleet
@@ -506,27 +512,23 @@ class FusedDenseCSVBatches(_FusedDenseTextBatches):
 
 class _EllSlotMixin:
     """Shared ELL ring-slot layout for the fused ELL producers: each slot
-    is (indices, values, nnz, labels, weights, packed) views over ONE
-    contiguous buffer → one DMA per staged batch. Classes using it carry
-    ``spec``, ``rows_out`` and ``truncated_nnz``."""
+    is (indices, values, nnz, labels, weights[, fields], packed) views
+    over ONE contiguous buffer (``batcher.ell_sections``; ``fields`` only
+    when the spec stages field ids) → one DMA per staged batch. Classes
+    using it carry ``spec``, ``rows_out`` and ``truncated_nnz``."""
 
     def _alloc_ell_slot(self):
-        spec = self.spec
-        B, K = spec.batch_size, int(spec.max_nnz)  # type: ignore[arg-type]
-        buf, v = alloc_packed_slot(
-            [
-                ("indices", (B, K), np.int32),
-                ("values", (B, K), spec.value_dtype),
-                ("nnz", (B,), np.int32),
-                ("labels", (B,), np.float32),
-                ("weights", (B,), np.float32),
-            ]
-        )
-        return (v["indices"], v["values"], v["nnz"], v["labels"],
-                v["weights"], buf)
+        buf, v = alloc_packed_slot(ell_sections(self.spec))
+        return tuple(v.values()) + (buf,)
+
+    @staticmethod
+    def _slot_fields(slot):
+        """The slot's ``fields`` view, or None when it stages none."""
+        return slot[5] if len(slot) == 7 else None
 
     def _emit_ell(self, slot, n_valid: int) -> Batch:
-        indices, values, nnz, labels, weights, packed = slot
+        indices, values, nnz, labels, weights = slot[:5]
+        fields, packed = self._slot_fields(slot), slot[-1]
         self.rows_out += n_valid
         _ROWS_OUT.inc(n_valid)
         if self.spec.overflow == "error" and self.truncated_nnz:
@@ -536,16 +538,13 @@ class _EllSlotMixin:
             )
         return Batch(
             labels=labels, weights=weights, n_valid=n_valid,
-            indices=indices, values=values, nnz=nnz, packed=packed,
+            indices=indices, values=values, nnz=nnz, fields=fields,
+            packed=packed,
         )
 
     def _pad_ell_tail(self, slot, fill: int) -> None:
-        indices, values, nnz, labels, weights, _packed = slot
-        indices[fill:] = 0
-        values[fill:] = 0
-        nnz[fill:] = 0
-        labels[fill:] = 0
-        weights[fill:] = 0
+        for a in slot[:-1]:
+            a[fill:] = 0
 
 
 class FusedEllRowRecBatches(_EllSlotMixin):
@@ -583,6 +582,7 @@ class FusedEllRowRecBatches(_EllSlotMixin):
     ) -> None:
         check(native.HAS_ELL, "native fused ELL kernel not loaded")
         check(spec.layout == "ell", "fused rowrec path requires layout='ell'")
+        check(not spec.fields, "rowrec records carry no field ids")
         check(spec.value_dtype in (np.dtype(np.float32), np.dtype(np.float16)),
               f"fused path supports f32/f16 values, not {spec.value_dtype}")
         check(spec.index_dtype == np.dtype(np.int32),
@@ -657,7 +657,7 @@ class FusedEllRowRecBatches(_EllSlotMixin):
     def _feed(self, chunk, off: int, fill: int):
         """Parse chunk[off:] into the current slot; returns updated
         (off, fill, made_progress)."""
-        indices, values, nnz, labels, weights, _packed = self._ring[self._slot]
+        indices, values, nnz, labels, weights = self._ring[self._slot][:5]
         with annotate("dmlc:parse"):
             rows, consumed, trunc, bad, corrupt = native.parse_rowrec_ell(
                 chunk, off, indices, values, nnz, labels, weights, fill
@@ -755,7 +755,7 @@ class FusedEllRowRecBatches(_EllSlotMixin):
             off, n = 0, len(starts)
             while off < n:
                 slot = self._ring[self._slot]
-                indices, values, nnz, labels, weights, _packed = slot
+                indices, values, nnz, labels, weights = slot[:5]
                 with annotate("dmlc:parse"):
                     rows, consumed, trunc, bad, corrupt = (
                         native.parse_rowrec_gather_ell(
@@ -1038,9 +1038,11 @@ class FusedEllLibFMBatches(_EllSlotMixin, _FusedTextBatches):
     """libfm text → ELL [B,K] via dmlc_parse_libfm_ell.
 
     Semantics match LibFMParser + FixedShapeBatcher('ell') composed
-    (reference libfm_parser.h:67-144 tolerant tokenization; fields are
-    validated then dropped — the ELL device layout carries no field
-    axis, exactly like the generic batcher). ``indexing_mode`` rides the
+    (reference libfm_parser.h:67-144 tolerant tokenization). Field ids
+    are validated, then staged as ``fields`` [B,K] when the spec asks
+    (``BatchSpec.fields``; the same base as the feature ids, padding
+    slots field 0) and dropped otherwise, exactly like the generic
+    batcher. ``indexing_mode`` rides the
     constructor or ``?indexing_mode=`` on the URI; auto (-1) resolves
     ONCE against the file head so shards can never disagree.
     """
@@ -1077,10 +1079,10 @@ class FusedEllLibFMBatches(_EllSlotMixin, _FusedTextBatches):
         return self._alloc_ell_slot()
 
     def _parse(self, chunk, off, slot, fill, cr_hint):
-        indices, values, nnz, labels, weights, _packed = slot
+        indices, values, nnz, labels, weights = slot[:5]
         rows, consumed, trunc, cr_hint = native.parse_libfm_ell(
             chunk, off, self._base or 0, indices, values, nnz, labels,
-            weights, fill, cr_hint,
+            weights, fill, cr_hint, self._slot_fields(slot),
         )
         self.truncated_nnz += trunc
         return rows, consumed, cr_hint
@@ -1116,6 +1118,7 @@ class FusedEllLibSVMBatches(_EllSlotMixin, _FusedTextBatches):
         check(native.HAS_LIBSVM_ELL,
               "native fused libsvm ELL kernel not loaded")
         check(spec.layout == "ell", "fused libsvm path requires layout='ell'")
+        check(not spec.fields, "libsvm text carries no field ids")
         check(spec.index_dtype == np.dtype(np.int32),
               "fused ELL path stages int32 indices")
         super().__init__(uri, spec, part_index, num_parts, ring)
@@ -1137,7 +1140,7 @@ class FusedEllLibSVMBatches(_EllSlotMixin, _FusedTextBatches):
         return self._alloc_ell_slot()
 
     def _parse(self, chunk, off, slot, fill, cr_hint):
-        indices, values, nnz, labels, weights, _packed = slot
+        indices, values, nnz, labels, weights = slot[:5]
         rows, consumed, trunc, cr_hint = native.parse_libsvm_ell(
             chunk, off, self._base or 0, indices, values, nnz, labels,
             weights, fill, cr_hint,

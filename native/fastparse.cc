@@ -940,6 +940,7 @@ struct EllState {
   int64_t K;
   bool f16;
   int64_t truncated;
+  int32_t* fields = nullptr;  // [capacity, K] field ids, or null: none
 };
 
 // Per-row ELL writer shared by the text->ELL kernels (libsvm/libfm): the
@@ -951,6 +952,7 @@ struct EllRowWriter {
   int32_t* irow;
   uint16_t* vrow16;
   float* vrow32;
+  int32_t* frow;  // null unless the batch stages field ids
   uint64_t ubase;
   int64_t k = 0;     // parsed-feature position within the row
   int64_t kept = 0;  // features stored with a valid id
@@ -962,23 +964,28 @@ struct EllRowWriter {
                      : nullptr),
         vrow32(s.f16 ? nullptr
                      : static_cast<float*>(s.values) + row * s.K),
+        frow(s.fields ? s.fields + row * s.K : nullptr),
         ubase(base) {}
 
   // first K parsed features keep token positions; ids outside int32
   // after base subtraction (incl. 1-based wraparound of id 0) are
   // zeroed in place + counted truncated; features beyond K dropped
-  // + counted
-  inline void store(int64_t feat, double v) {
+  // + counted. With field ids staged, the field follows the id's base
+  // and the same rule (a field outside int32 zeroes the slot too).
+  inline void store(int64_t feat, double v, int64_t fid = 0) {
     if (k < st.K) {
       const uint64_t col = static_cast<uint64_t>(feat) - ubase;
-      if (col > 0x7fffffffu) {
+      const uint64_t fcol = static_cast<uint64_t>(fid) - ubase;
+      if (col > 0x7fffffffu || (frow && fcol > 0x7fffffffu)) {
         irow[k] = 0;
         if (st.f16) vrow16[k] = 0; else vrow32[k] = 0.0f;
+        if (frow) frow[k] = 0;
         ++st.truncated;
       } else {
         irow[k] = static_cast<int32_t>(col);
         if (st.f16) vrow16[k] = f32_to_f16(static_cast<float>(v));
         else vrow32[k] = static_cast<float>(v);
+        if (frow) frow[k] = static_cast<int32_t>(fcol);
         ++kept;
       }
     } else {
@@ -991,6 +998,9 @@ struct EllRowWriter {
   inline void finish(int64_t row) {
     const int64_t filled = k < st.K ? k : st.K;
     std::memset(irow + filled, 0, static_cast<size_t>(st.K - filled) * 4);
+    if (frow) {
+      std::memset(frow + filled, 0, static_cast<size_t>(st.K - filled) * 4);
+    }
     if (st.f16) {
       std::memset(vrow16 + filled, 0,
                   static_cast<size_t>(st.K - filled) * 2);
@@ -1324,18 +1334,23 @@ DMLC_API void dmlc_walk_record_spans(
 //     that fall outside int32 after base subtraction (incl. 1-based
 //     wraparound of id 0) are zeroed in place and counted truncated;
 //     features beyond max_nnz are dropped and counted;
-//   - fields are parsed (a malformed field skips the token) and then
-//     DROPPED: the ELL device layout carries no field axis, exactly like
-//     the generic batcher path (staging/batcher.py _to_ell).
+//   - fields are parsed (a malformed field skips the token); with
+//     `fields` non-null each kept feature's field id (base subtracted,
+//     as the feature id) is written at the feature's position, padding
+//     slots get field 0, and a field outside int32 zeroes the slot as an
+//     id outside int32 does; with `fields` null they are dropped and the
+//     other arrays are byte-identical to a run with fields (the generic
+//     batcher path does the same, staging/batcher.py _to_ell).
 // `base` is the resolved indexing base (callers resolve libfm auto mode
 // against the file head, as the fused libsvm path does).
 
 DMLC_API void dmlc_parse_libfm_ell(
     const char* buf, int64_t len, int32_t base, int64_t max_nnz,
     int32_t out_f16, int32_t* indices, void* values, int32_t* nnz,
-    float* labels, float* weights, int64_t row_start, int64_t row_capacity,
-    int32_t cr_hint, DenseResult* out) {
-  EllState st{indices, values, nnz, labels, weights, max_nnz, out_f16 != 0, 0};
+    float* labels, float* weights, int32_t* fields, int64_t row_start,
+    int64_t row_capacity, int32_t cr_hint, DenseResult* out) {
+  EllState st{indices, values, nnz, labels, weights, max_nnz, out_f16 != 0, 0,
+              fields};
   const uint64_t ubase = static_cast<uint64_t>(base);
   const bool has_cr = walk_dense_lines(
       buf, len, row_start, row_capacity, cr_hint, out,
@@ -1344,8 +1359,7 @@ DMLC_API void dmlc_parse_libfm_ell(
         if (!parse_label_token(&p, le, st, row)) return false;
 
         EllRowWriter w(st, row, ubase);
-        const auto store = [&](int64_t feat, double v) { w.store(feat, v); };
-        const char* te;
+                const char* te;
         while (p < le) {
           while (p < le && is_blank(*p)) ++p;
           if (p >= le) break;
@@ -1353,11 +1367,19 @@ DMLC_API void dmlc_parse_libfm_ell(
           // (the same fused scan style as the libsvm dense kernel) ----
           const char* q = p;
           int fd = 0;
-          if (q < le && *q == '-') ++q;
-          while (q < le && *q >= '0' && *q <= '9' && fd <= 18) {
+          bool fneg = false;
+          uint64_t fid = 0;
+          if (q < le && *q == '-') {
+            fneg = true;
             ++q;
-            ++fd;  // fid digits: validity only, the value is dropped
           }
+          while (q < le && *q >= '0' && *q <= '9' && fd <= 18) {
+            fid = fid * 10 + static_cast<uint64_t>(*q - '0');
+            ++q;
+            ++fd;
+          }
+          const int64_t sfid =
+              fneg ? -static_cast<int64_t>(fid) : static_cast<int64_t>(fid);
           if (fd > 0 && fd <= 18 && q < le && *q == ':') {
             ++q;
             bool gneg = false;
@@ -1377,7 +1399,7 @@ DMLC_API void dmlc_parse_libfm_ell(
                   gneg ? -static_cast<int64_t>(feat)
                        : static_cast<int64_t>(feat);
               if (q >= le || is_blank(*q)) {
-                store(sfeat, 1.0);  // bare pair fid:feat
+                w.store(sfeat, 1.0, sfid);  // bare pair fid:feat
                 p = q;
                 continue;
               }
@@ -1385,7 +1407,7 @@ DMLC_API void dmlc_parse_libfm_ell(
                 ++q;
                 double v;
                 if (scan_decimal_value(&q, le, &v)) {
-                  store(sfeat, v);
+                  w.store(sfeat, v, sfid);
                   p = q;
                   continue;
                 }
@@ -1409,7 +1431,7 @@ DMLC_API void dmlc_parse_libfm_ell(
                          parse_float_full(c2 + 1, te, &v))
                       : parse_i64_full(c1 + 1, te, &feat);
             }
-            if (ok) store(feat, v);
+            if (ok) w.store(feat, v, fid);
           }
           p = te;
         }

@@ -252,3 +252,123 @@ def test_generic_fallback_without_native(tmp_path, monkeypatch):
     s = ell_batches(path + "?format=libfm", _spec())
     assert sum(int(b.n_valid) for b in s) == 80
     s.close()
+
+
+# -- field ids (BatchSpec.fields) ---------------------------------------------
+
+
+def _field_spec(value_dtype="float32", B=64, K=4, fields=True):
+    return BatchSpec(
+        batch_size=B, layout="ell", max_nnz=K,
+        value_dtype=np.dtype(value_dtype), fields=fields,
+    )
+
+
+def _fused_copies(path, spec, part_index=0, num_parts=1, indexing_mode=0):
+    from dmlc_core_tpu.staging import FusedEllLibFMBatches
+
+    stream = FusedEllLibFMBatches(
+        path, spec, part_index, num_parts, indexing_mode=indexing_mode
+    )
+    out = [{k: v.copy() for k, v in b.as_dict().items()}
+           | {"packed": b.packed.copy()} for b in stream]
+    tr = stream.truncated_nnz
+    stream.close()
+    return out, tr
+
+
+def _plain_fields(path, B, K, one_based):
+    """Field ids as the pure-Python parser (data/libfm_parser.py) reads
+    them, placed at their tokens' positions in [B, K] batches: the
+    first K tokens of a row kept, a field outside int32 (a 1-based
+    field 0) dropping its slot, padding slots field 0."""
+    from dmlc_core_tpu.data.libfm_parser import LibFMParser
+
+    parser = LibFMParser.__new__(LibFMParser)
+    parser.param = type("P", (), {"indexing_mode": 1 if one_based else 0})()
+    parser.index_dtype = np.int64
+    blk = parser._parse_block_py(open(path, "rb").read())
+    rows = []
+    for r in range(blk.size):
+        lo, hi = blk.offset[r], blk.offset[r + 1]
+        row = np.zeros(K, np.int32)
+        for p, (f, j) in enumerate(zip(blk.field[lo:hi], blk.index[lo:hi])):
+            if p < K and 0 <= f < 2**31 and 0 <= j < 2**31:
+                row[p] = f
+        rows.append(row)
+    out = []
+    for b0 in range(0, len(rows), B):
+        batch = np.zeros((B, K), np.int32)
+        chunk = rows[b0 : b0 + B]
+        batch[: len(chunk)] = chunk
+        out.append(batch)
+    return out
+
+
+@fused
+@pytest.mark.parametrize("one_based", [False, True], ids=["base0", "base1"])
+@pytest.mark.parametrize("value_dtype", ["float32", "float16"])
+def test_fused_fields_match_the_plain_parser(tmp_path, value_dtype, one_based):
+    """Fields bit-identical to data/libfm_parser.py at both indexing
+    bases, with rows past K truncated and a padded tail batch (500 rows,
+    B = 64), junk tokens and a 1-based field 0 in the mix; the generic
+    batcher stages the same bytes."""
+    path = _write_libfm(str(tmp_path / "f.libfm"), rows=500, k_max=7,
+                        one_based=one_based, junk=True)
+    with open(path, "a") as f:
+        f.write("1 0:5:1.0 3:7:2.0\n")  # field 0: out of range when 1-based
+    mode = 1 if one_based else 0
+    f, ft = _fused_copies(path, _field_spec(value_dtype), indexing_mode=mode)
+    want = _plain_fields(path, 64, 4, one_based)
+    assert len(f) == len(want) == 8
+    for b, w in zip(f, want):
+        assert b["fields"].dtype == np.int32
+        np.testing.assert_array_equal(b["fields"], w)
+    assert f[-1]["fields"][f[-1]["weights"] == 0].max() == 0
+    g, gt = _generic(path, _field_spec(value_dtype), indexing_mode=mode)
+    assert ft == gt and ft > 0
+    for b, gb in zip(f, g):
+        for k, v in gb.as_dict().items():
+            assert v.tobytes() == b[k].tobytes(), k
+
+
+@fused
+def test_fused_fields_cover_several_parts(tmp_path):
+    path = _write_libfm(str(tmp_path / "p.libfm"), rows=400, k_max=7)
+    for part in range(3):
+        f, _ = _fused_copies(path, _field_spec(B=32), part, 3)
+        g, _ = _generic(path, _field_spec(B=32), part, 3)
+        assert len(f) == len(g) > 0
+        for b, gb in zip(f, g):
+            np.testing.assert_array_equal(b["fields"], gb.fields)
+            np.testing.assert_array_equal(b["indices"], gb.indices)
+
+
+@fused
+@pytest.mark.parametrize("value_dtype", ["float32", "float16"])
+def test_fields_off_leaves_the_staged_bytes_unchanged(tmp_path, value_dtype):
+    """The fields section is appended after every other one: with it
+    off, each packed slot is byte for byte the prefix of the slot with
+    it on, and every other array is the same."""
+    path = _write_libfm(str(tmp_path / "u.libfm"), rows=300, k_max=7,
+                        junk=True)
+    off, t_off = _fused_copies(path, _field_spec(value_dtype, fields=False))
+    on, t_on = _fused_copies(path, _field_spec(value_dtype))
+    assert t_off == t_on
+    for a, b in zip(off, on):
+        assert "fields" not in a
+        assert b["packed"][: a["packed"].nbytes].tobytes() == a["packed"].tobytes()
+        for k in set(a) - {"packed"}:
+            assert a[k].tobytes() == b[k].tobytes(), k
+
+
+def test_fields_need_libfm_input(tmp_path):
+    from dmlc_core_tpu.utils.logging import Error
+
+    with pytest.raises(Error, match="field"):
+        BatchSpec(batch_size=8, layout="dense", num_features=4, fields=True)
+    path = tmp_path / "d.libsvm"
+    path.write_text("1 1:2 3:4\n")
+    with pytest.raises(Error, match="field"):
+        s = ell_batches(str(path) + "?format=libsvm", _field_spec(B=8))
+        list(s)

@@ -409,3 +409,40 @@ def test_drain_close_defers_source_on_timed_out_join():
     assert drain_close(pipe, src) is False
     assert pipe.close_timed_out is True
     assert src.closed is False, "source freed under a live reader thread"
+
+
+def _fields_block(sizes, base=0, n_fields=7):
+    blk = ragged_block(sizes, base)
+    return RowBlock(offset=blk.offset, label=blk.label, index=blk.index,
+                    value=blk.value, field=np.arange(blk.nnz) % n_fields)
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (4,)], ids=["one", "mesh"])
+def test_staging_pipeline_carries_field_ids(mesh_shape):
+    """``fields`` rides the packed DMA (one put a batch, or one a device
+    on a mesh) and comes out of the unpack with the other arrays; the
+    staged bytes count it."""
+    import jax
+    from jax.sharding import Mesh
+
+    spec = BatchSpec(batch_size=8, layout="ell", max_nnz=4, fields=True)
+    blocks = [_fields_block([3, 4, 2, 5, 1, 4], base=6 * i) for i in range(4)]
+    want = list(FixedShapeBatcher(spec).batches(iter(blocks)))
+    kw = {}
+    if mesh_shape is not None:
+        kw = {"mesh": Mesh(np.array(jax.devices()[:4]), ("data",)),
+              "data_axis": "data"}
+    pipe = StagingPipeline(FixedShapeBatcher(spec).batches(iter(blocks)),
+                           depth=2, **kw)
+    got = list(pipe)
+    assert len(got) == len(want) == 3
+    for dev, host in zip(got, want):
+        assert set(dev) == set(host.as_dict()) and "fields" in dev
+        for k, v in host.as_dict().items():
+            assert np.asarray(dev[k]).tobytes() == v.tobytes(), k
+    st = pipe.staging_stats()
+    path = "packed_batches" if mesh_shape is None else "packed_shard_batches"
+    assert st[path] == 3 and st["per_array_batches"] == 0
+    assert pipe.bytes_staged == sum(
+        v.nbytes for b in want for v in b.as_dict().values())
+    pipe.close()

@@ -304,3 +304,56 @@ def test_spmd_fm_step_loss_waits_for_the_update(fm_2x2):
     whole = [i for i, ln in enumerate(entry)
              if " all-reduce(" in ln and "replica_groups={{0,1,2,3}}" in ln]
     assert whole and whole[-1] > updates[-1]
+
+
+# -- the field-aware FM (criteo_ffm): libffm's k 4 on Criteo's 39 fields, 10^6
+# hashed bins, W and G [10^6, 256] -------------------------------------------
+
+FFM_FEATURES, FFM_FIELDS, FFM_K = 10**6, 39, 4
+
+
+@pytest.fixture(scope="module")
+def ffm_one_chip(one_chip):
+    from dmlc_core_tpu.models import FieldAwareFM, ffm
+
+    model = FieldAwareFM(FFM_FEATURES, FFM_FIELDS, k=FFM_K)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+              for k, s in shapes.items()}
+    batch = _criteo_batch_structs(lambda _shape: one_chip)
+    batch["fields"] = jax.ShapeDtypeStruct((B, K), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, b: model.adagrad_step(p, b, lr=0.2))
+    rule = ffm._update_path
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ffm, "_update_path", lambda _p, *a: rule("tpu", *a))
+        return step.lower(params, batch).compile()
+
+
+def test_ffm_step_fits_one_chip(ffm_one_chip, record_property):
+    mem = ffm_one_chip.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    record_property("ffm_step_temp_bytes", int(mem.temp_size_in_bytes))
+    print(f"FFM adagrad_step 10^6 x 256: argument "
+          f"{mem.argument_size_in_bytes} output {mem.output_size_in_bytes} "
+          f"temp {mem.temp_size_in_bytes} B")
+    assert total < HBM_BYTES, mem
+
+
+def test_ffm_step_writes_both_tables_in_the_kernel(ffm_one_chip):
+    # one kernel writes the new W and G; XLA builds no table-sized
+    # gradient, scatters nothing into a table and copies no table
+    text = ffm_one_chip.as_text()
+    calls = re.findall(r"= \((.*?)\) custom-call\(.*?custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    table = f"f32[{FFM_FEATURES},256]"
+    assert [c.count(table) for c in calls] == [2]
+    for op in ("broadcast", "scatter", "copy", "transpose"):
+        assert ("f32", (FFM_FEATURES, 256)) not in _ops(text, op), op
+    # no intermediate has k = 4 or K = 39 lanes minor (128-lane padding)
+    for dt, dims, layout in re.findall(
+            r"= (f32)\[([\d,]+)\]\{([\d,]+)", text):
+        dims = [int(d) for d in dims.split(",")]
+        minor = dims[int(layout.split(",")[0])]
+        assert not (minor in (FFM_K, K) and np.prod(dims) > 1 << 20), (
+            dims, layout)
